@@ -99,11 +99,20 @@ class GPPosterior(NamedTuple):
         return int(self.x.shape[0])
 
 
+def _dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """Full-f32 contraction.  On TPU a default-precision f32 matmul
+    rounds its operands to bf16; the GP's distance expansion, predictive
+    means and bordered-Cholesky updates all subtract nearly equal terms,
+    so they run at HIGHEST.  CPU ignores the flag."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def _sqdist(a: jnp.ndarray, b: jnp.ndarray, ls: jnp.ndarray) -> jnp.ndarray:
     a = a / ls
     b = b / ls
     return jnp.maximum(
-        jnp.sum(a * a, -1)[:, None] - 2 * a @ b.T + jnp.sum(b * b, -1)[None],
+        jnp.sum(a * a, -1)[:, None] - 2 * _dot(a, b.T)
+        + jnp.sum(b * b, -1)[None],
         0.0)
 
 
@@ -139,7 +148,7 @@ def neg_mll(params: GPParams, x: jnp.ndarray, y: jnp.ndarray,
     chol = jnp.linalg.cholesky(k)
     ym = y * mask
     alpha = jax.scipy.linalg.cho_solve((chol, True), ym)
-    return (0.5 * ym @ alpha
+    return (0.5 * _dot(ym, alpha)
             + jnp.sum(jnp.log(jnp.diagonal(chol)))
             + 0.5 * jnp.sum(mask) * jnp.log(2 * jnp.pi))
 
@@ -476,7 +485,7 @@ def prewarm_bucket(d: int, bucket: int, fit_steps=(), k_pads=(),
 def predict(post: GPPosterior, xq: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Posterior mean/stddev at query points (m,d) — in raw y units."""
     kq = matern52(xq, post.x, post.params) * post.mask[None, :]   # (m,b)
-    mu = kq @ post.alpha
+    mu = _dot(kq, post.alpha)
     v = jax.scipy.linalg.solve_triangular(post.chol, kq.T, lower=True)
     amp2 = jnp.exp(2 * post.params.log_amp)
     var = jnp.maximum(amp2 - jnp.sum(v * v, axis=0), 1e-12)
@@ -506,7 +515,7 @@ def _append_norm(post: GPPosterior, xn: jnp.ndarray,
     kvec = (matern52(xn[None], post.x, post.params)[0] * post.mask)
     l12 = jax.scipy.linalg.solve_triangular(post.chol, kvec, lower=True)
     kss = jnp.exp(2 * post.params.log_amp) + _noise2(post.params)
-    l22 = jnp.sqrt(jnp.maximum(kss - l12 @ l12, 1e-10))
+    l22 = jnp.sqrt(jnp.maximum(kss - _dot(l12, l12), 1e-10))
     chol = post.chol.at[idx, :].set(l12.at[idx].set(l22))
     x = post.x.at[idx].set(xn)
     mask = post.mask.at[idx].set(1.0)
@@ -527,7 +536,7 @@ def append_point(post: GPPosterior, xn: jnp.ndarray,
 def append_lie(post: GPPosterior, xn: jnp.ndarray) -> GPPosterior:
     """Constant liar: pin a pending suggestion at its posterior mean."""
     kvec = matern52(xn[None], post.x, post.params)[0] * post.mask
-    return _append_norm(post, xn, kvec @ post.alpha)
+    return _append_norm(post, xn, _dot(kvec, post.alpha))
 
 
 @functools.partial(jax.jit, static_argnames=("k_pad",))
@@ -612,7 +621,7 @@ def _select_lanes(post: GPPosterior, cand: jnp.ndarray, best: jnp.ndarray,
 
     def lane_step(p, kq, v, ss, taken, c, b_inc, k1, i):
         amp2 = jnp.exp(2 * p.params.log_amp)
-        mu_n = kq @ p.alpha                                      # (m,)
+        mu_n = _dot(kq, p.alpha)                                 # (m,)
         var = jnp.maximum(amp2 - ss, 1e-12)
         mu = mu_n * p.y_std + p.y_mean
         sd = jnp.sqrt(var) * p.y_std
@@ -641,7 +650,7 @@ def _select_lanes(post: GPPosterior, cand: jnp.ndarray, best: jnp.ndarray,
         # one forward-substitution row, one variance partial
         kq_col = matern52(c, xn[None], p.params)[:, 0]           # (m,)
         kq2 = kq.at[:, idx].set(kq_col)
-        v_row = (kq_col - l12 @ v) / l22                         # (m,)
+        v_row = (kq_col - _dot(l12, v)) / l22                    # (m,)
         v2 = v.at[idx, :].set(v_row)
         ss2 = ss + v_row * v_row
         live = i < k1

@@ -15,7 +15,10 @@ TPU-native design (hardware adaptation per DESIGN.md):
   window) are skipped with @pl.when — compiled FLOPs match the triangular/
   banded workload like the XLA path in models/attention.py.
 
-Validated against kernels/ref.py in interpret mode (tests/test_kernels.py).
+Checked against kernels/ref.py in interpret mode on the CPU only
+(tests/test_kernels_gp.py, and tests/test_kernels.py where hypothesis is
+installed).  No model path calls it (``ModelConfig.use_pallas`` is not
+read), and it has not been compiled for or run on a TPU.
 """
 from __future__ import annotations
 

@@ -1,8 +1,14 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On TPU these dispatch to the pallas_call kernels; elsewhere (this CPU
-container, unit tests) they run the kernels in interpret mode or fall back
-to the jnp oracle — callers never branch on platform themselves.
+The platform decides the path, at trace time, and callers never branch
+on it themselves:
+
+* on TPU every wrapper runs its Pallas kernel compiled (never in
+  interpret mode).  There is no fallback: a kernel that fails to compile
+  raises to the caller;
+* on any other backend the wrappers run the jnp oracles in ``ref.py``,
+  unless the caller passes ``force_kernel=True`` (the parity tests and
+  ``chip_smoke.py`` do), which runs the kernel in interpret mode.
 """
 from __future__ import annotations
 

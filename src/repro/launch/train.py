@@ -74,6 +74,10 @@ def train(arch: str, steps: int, batch: int, seq: int, *, reduced=True,
     step_fn = jax.jit(step_fn, donate_argnums=0)
 
     state = S.init_train_state(cfg, jax.random.key(seed))
+    leaves = jax.tree.leaves(state["params"])
+    platforms = sorted({d.platform for a in leaves for d in a.devices()})
+    log(f"[train] {cfg.name}: {sum(a.size for a in leaves) / 1e6:.1f}M "
+        f"params on {','.join(platforms)}")
     start = 0
     mgr = CheckpointManager(ckpt_dir) if ckpt_dir else None
     if resume and mgr and mgr.latest_step() is not None:
@@ -133,4 +137,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()
