@@ -48,6 +48,7 @@ from repro.api.protocol import (ApiError, BatchRequest, BatchResponse,
 from repro.api.transport import (FLUSH_DEADLINE_S, FLUSH_MAX_OPS,
                                  DecisionGate, OP_OBSERVE, OP_RELEASE,
                                  OP_REPORT, WriteBehind)
+from repro.core import tracing
 from repro.core.store import Store
 
 
@@ -117,16 +118,19 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _dispatch(self, method: str) -> None:
         self._body = None
-        try:
-            exp_id, action, trial_id = _parse_path(self.path)
-            self._send(200, self._route(method, exp_id, action, trial_id))
-        except ApiError as e:
-            self._send(e.http_status, e.to_json())
-        except Exception as e:  # noqa: service must answer, not die
-            err = ApiError(E_INTERNAL, f"{type(e).__name__}: {e}")
-            self._send(err.http_status, err.to_json())
-        finally:
-            self._take_body()   # drain for keep-alive reuse
+        with tracing.request_span("http.request") as sp:
+            try:
+                exp_id, action, trial_id = _parse_path(self.path)
+                sp.set(route=action)
+                self._send(200, self._route(method, exp_id, action,
+                                            trial_id))
+            except ApiError as e:
+                self._send(e.http_status, e.to_json())
+            except Exception as e:  # noqa: service must answer, not die
+                err = ApiError(E_INTERNAL, f"{type(e).__name__}: {e}")
+                self._send(err.http_status, err.to_json())
+            finally:
+                self._take_body()   # drain for keep-alive reuse
 
     def _route(self, method: str, exp_id: Optional[str],
                action: Optional[str],

@@ -54,6 +54,7 @@ from repro.api.protocol import (ApiError, BatchOpResult, BatchRequest,
                                 ReleaseResponse, ReportRequest,
                                 RequeueRequest, StatusResponse, SuggestBatch,
                                 Suggestion, epoch_tuple)
+from repro.core import tracing
 from repro.core.experiment import ExperimentConfig
 from repro.core.space import strip_internal
 from repro.core.store import FencedError, Store
@@ -94,7 +95,6 @@ class _ExperimentState:
                       "invalidated": 0, "prefilled": 0, "prewarmed": 0,
                       "batched_prefilled": 0,
                       "sparse_prefilled": 0, "sparse_served": 0,
-                      "requeued": 0, "requeue_served": 0,
                       # sparse-vs-exact quality on finished trials (the
                       # SPARSE_MAX tuning signal, ROADMAP sparse quality)
                       "sparse_obs": 0, "sparse_regret": 0.0,
@@ -426,24 +426,30 @@ class LocalClient(SuggestionClient):
         lock; whoever wins serves every parked slot with one batched
         ``ask`` (cross-scheduler coalescing).  Losers just wait — their
         suggestions are computed by the winner (or the pump)."""
-        slot = MissSlot(need)
-        with state.lock:
-            if state.stopped:
-                return []
-            state.miss_slots.append(slot)
-        while not slot.done:
-            if state.opt_lock.acquire(timeout=0.02):
-                try:
-                    if not slot.done:
-                        serve_misses(state, lambda a: self._mint(state, a))
-                finally:
-                    state.opt_lock.release()
-            else:
-                slot.event.wait(0.02)
-        return slot.result
+        with tracing.span("suggest.miss_wait") as sp:
+            slot = MissSlot(need, sp.request_id)
+            with state.lock:
+                if state.stopped:
+                    return []
+                state.miss_slots.append(slot)
+            while not slot.done:
+                if state.opt_lock.acquire(timeout=0.02):
+                    try:
+                        if not slot.done:
+                            serve_misses(state,
+                                         lambda a: self._mint(state, a))
+                    finally:
+                        state.opt_lock.release()
+                else:
+                    slot.event.wait(0.02)
+            return slot.result
 
     # ------------------------------------------------------ suggest/observe
     def suggest(self, exp_id: str, count: int = 1) -> SuggestBatch:
+        with tracing.span("suggest"):
+            return self._suggest(exp_id, count)
+
+    def _suggest(self, exp_id: str, count: int) -> SuggestBatch:
         state = self._state(exp_id)
         if state.fenced:
             # cheap flag check only — serving from a not-yet-detected
@@ -452,7 +458,9 @@ class LocalClient(SuggestionClient):
             raise ApiError(E_FENCED,
                            f"{exp_id}: this incarnation was fenced")
         self._ensure_pump(exp_id, state)
-        with state.lock:
+        with tracing.span("suggest.lock_wait"):
+            state.lock.acquire()
+        try:
             if state.stopped:
                 return SuggestBatch([], remaining=0)
             # requeued (orphaned) suggestions are served first: they are
@@ -465,7 +473,6 @@ class LocalClient(SuggestionClient):
                         or s.suggestion_id not in state.pending):
                     continue    # observed/released while parked
                 batch.append(s)
-                state.stats["requeue_served"] += 1
             headroom = (state.cfg.budget - state.observed
                         - len(state.pending))
             n = max(0, min(int(count) - len(batch), headroom))
@@ -477,6 +484,8 @@ class LocalClient(SuggestionClient):
                 state.ops.extend(("forget", a) for a in stale)
             pump = state.pump
             refill = len(state.queue) < state.pump_depth()
+        finally:
+            state.lock.release()
         if pump is not None and pump.alive:
             if refill or stale or need:
                 pump.wake()
@@ -693,7 +702,6 @@ class LocalClient(SuggestionClient):
         if all(o.suggestion_id != suggestion_id
                for o in state.orphaned):
             state.orphaned.append(s)
-            state.stats["requeued"] += 1
         return True
 
     # ------------------------------------------------------------- batching

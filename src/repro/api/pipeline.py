@@ -54,6 +54,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from repro.core import tracing
+
 #: Largest ``ask`` the pipeline issues per optimizer-lock hold (pump
 #: refill ticks and coalesced miss rounds alike).  Bounds lock latency
 #: (a request arriving mid-batch waits one chunk, not one queue fill)
@@ -180,7 +182,8 @@ class FitExecutor:
         self.workers = workers
         self._cv = threading.Condition()
         self._heap: List[tuple] = []            # (prio, seq, key)
-        self._jobs: Dict[Any, tuple] = {}       # key -> (prio, fn)
+        # key -> (prio, fn, monotonic ns of the key's first submit)
+        self._jobs: Dict[Any, tuple] = {}
         self._active: set = set()               # keys running on a worker
         self._seq = 0
         self._stopped = False
@@ -207,6 +210,7 @@ class FitExecutor:
         the most urgent priority).  ``fn`` runs on a worker thread and
         returns True to be requeued (e.g. it lost an optimizer-lock
         race)."""
+        now = time.monotonic_ns()
         with self._cv:
             if self._stopped:
                 return
@@ -221,14 +225,14 @@ class FitExecutor:
             if cur is not None:
                 self.stats["coalesced"] += 1
                 if prio < cur[0]:       # escalate: push a fresher entry;
-                    self._jobs[key] = (prio, fn)    # the stale one is
-                    self._seq += 1                  # skipped at pop time
+                    self._jobs[key] = (prio, fn, cur[2])  # the stale one
+                    self._seq += 1              # is skipped at pop time
                     heapq.heappush(self._heap, (prio, self._seq, key))
                     self._cv.notify()
                 else:
-                    self._jobs[key] = (cur[0], fn)
+                    self._jobs[key] = (cur[0], fn, cur[2])
                 return
-            self._jobs[key] = (prio, fn)
+            self._jobs[key] = (prio, fn, now)
             self._seq += 1
             heapq.heappush(self._heap, (prio, self._seq, key))
             self._cv.notify()
@@ -335,6 +339,8 @@ class FitExecutor:
                     if cur is not None and cur[0] == prio:
                         del self._jobs[key]
                         self._active.add(key)
+                        tracing.record("exec.queue_wait", cur[2],
+                                       time.monotonic_ns(), prio=prio)
                         return key, cur[1], prio
                 self._cv.wait(self.IDLE_WAIT)
                 if not self._heap:
@@ -395,7 +401,8 @@ class FitExecutor:
 
         Returns (requeue_primary, seconds_slept) — the sleep is
         subtracted from the duty-cycle accounting by ``_run``."""
-        lane = fn.snapshot()
+        with tracing.span("exec.snapshot"):
+            lane = fn.snapshot()
         if lane is RETRY:
             return True, 0.0
         if lane is None:
@@ -404,7 +411,8 @@ class FitExecutor:
         if prio > PRIO_MISS and self.GATHER_WINDOW > 0.0:
             # deliberate plain sleep (not a _cv wait): we *want* to stay
             # out of the way while pumps enqueue peers
-            time.sleep(self.GATHER_WINDOW)
+            with tracing.span("exec.gather_wait"):
+                time.sleep(self.GATHER_WINDOW)
             slept = self.GATHER_WINDOW
         grabbed: List[tuple] = []
         lanes_cap = self.max_lanes()
@@ -412,15 +420,18 @@ class FitExecutor:
             for k2 in list(self._jobs):
                 if 1 + len(grabbed) >= lanes_cap:
                     break
-                p2, f2 = self._jobs[k2]
+                p2, f2, t2 = self._jobs[k2]
                 if isinstance(f2, BatchableFit):
                     del self._jobs[k2]
                     self._active.add(k2)
                     grabbed.append((k2, p2, f2))
+                    tracing.record("exec.queue_wait", t2,
+                                   time.monotonic_ns(), prio=p2)
         lanes = [(key, lane)]
         for k2, p2, f2 in grabbed:
             try:
-                l2 = f2.snapshot()
+                with tracing.span("exec.snapshot"):
+                    l2 = f2.snapshot()
             except Exception as e:  # noqa: peer snapshot must not kill batch
                 with self._cv:
                     self._active.discard(k2)
@@ -437,18 +448,22 @@ class FitExecutor:
                 self._active.discard(k2)
             if l2 is not None:      # RETRY or mismatched group: still owed
                 self.submit(k2, f2, p2)
+        is_ask = getattr(lane.spec, "kind", "fit") == "ask"
         try:
-            out, dt = lane.spec.runner([l.spec for _, l in lanes])
+            with tracing.span("exec.dispatch",
+                              kind="ask" if is_ask else "fit",
+                              lanes=len(lanes), bucket=lane.spec.bucket):
+                out, dt = lane.spec.runner([l.spec for _, l in lanes])
             per = dt / max(1, len(lanes))
             failed = 0
             err = None
-            for (_, l), params in zip(lanes, out):
-                try:
-                    l.install(params, per)
-                except Exception as e:  # noqa: one bad install ≠ batch loss
-                    failed += 1
-                    err = f"{type(e).__name__}: {e}"
-            is_ask = getattr(lane.spec, "kind", "fit") == "ask"
+            with tracing.span("exec.install", lanes=len(lanes)):
+                for (_, l), params in zip(lanes, out):
+                    try:
+                        l.install(params, per)
+                    except Exception as e:  # noqa: one bad install, not the batch
+                        failed += 1
+                        err = f"{type(e).__name__}: {e}"
             with self._cv:
                 # fit and ask dispatches count separately, so mean_batch
                 # stays a pure fit-co-batching signal (tests pin it)
@@ -527,11 +542,13 @@ class PrefetchItem:
 class MissSlot:
     """A ``suggest`` call waiting out a queue miss.  Filled (with up to
     ``need`` suggestions — possibly fewer, budget permitting) by whichever
-    thread wins the optimizer lock and serves the coalesced batch."""
-    __slots__ = ("need", "event", "result", "done")
+    thread wins the optimizer lock and serves the coalesced batch.
+    ``request_id`` is the parked request's span id while tracing is on."""
+    __slots__ = ("need", "event", "result", "done", "request_id")
 
-    def __init__(self, need: int):
+    def __init__(self, need: int, request_id: Optional[int] = None):
         self.need = need
+        self.request_id = request_id
         self.event = threading.Event()
         self.result: List[Any] = []
         self.done = False
@@ -639,7 +656,10 @@ def serve_misses(state, make_suggestion: Callable[[Dict[str, Any]], Any]) -> int
             headroom = (state.cfg.budget - state.observed
                         - len(state.pending))
             total = min(sum(s.need for s in slots), max(0, headroom))
-    assigns = state.optimizer.ask(total) if total > 0 else []
+    # the model pass, naming the parked requests it answers
+    with tracing.span("miss.serve", slots=len(slots),
+                      requests=[s.request_id for s in slots]):
+        assigns = state.optimizer.ask(total) if total > 0 else []
     with state.lock:
         # headroom may have shrunk while we computed (queue pops register
         # pending under state.lock only) — never overdraw the budget
@@ -734,7 +754,8 @@ class SuggestionPump:
         try:
             self._prewarm()
             while not self._stop.is_set():
-                busy = self._tick()
+                with tracing.span("pump.tick"):
+                    busy = self._tick()
                 if self._stop.is_set() or self._finished():
                     break
                 if not busy:
@@ -776,7 +797,9 @@ class SuggestionPump:
         the pump thread only reconditions and pops."""
         state = self.state
         self._prewarm()     # cheap no-op once the goal bucket is compiled
-        if not state.opt_lock.acquire(timeout=0.1):
+        with tracing.span("pump.lock_wait"):
+            locked = state.opt_lock.acquire(timeout=0.1)
+        if not locked:
             return True     # contended: re-check stop flag, then retry
         try:
             if self._stop.is_set():

@@ -36,6 +36,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.space import Assignment, Space, strip_internal as _clean
 from repro.core.suggest import gp
 from repro.core.suggest.base import Observation, Optimizer, register
@@ -269,8 +270,7 @@ class BayesOpt(Optimizer):
                 "fit_ms": ms(self._fit_ema),
                 "arrival_ms": ms(self._arrival_ema),
                 "sparse_asks": self._sparse_asks,
-                "sparse_m": self._sparse_m,
-                "sparse_max": self._sparse_max}
+                "sparse_m": self._sparse_m}
 
     # ------------------------------------------------------------------
     def prewarm(self, max_history: int, batch: int = 8) -> int:
@@ -340,16 +340,15 @@ class BayesOpt(Optimizer):
         steps = (self.warm_steps() if self._params is not None
                  else self.fit_steps)
         t0 = time.perf_counter()
-        post = gp.fit_gp(x, y, steps=steps, params0=self._params,
-                         bucket=bucket)
+        with tracing.span("opt.fit", steps=steps, bucket=bucket):
+            post = gp.fit_gp(x, y, steps=steps, params0=self._params,
+                             bucket=bucket)
         dt = time.perf_counter() - t0
         self._fit_ema = dt if self._fit_ema is None \
             else 0.7 * self._fit_ema + 0.3 * dt
         self._fits += 1
         self._params = post.params
-        for u in self._pending.values():
-            post = gp.append_lie(post, np.asarray(u, np.float32))
-        self._post = post
+        self._post = self._fold_lies(post)
         self._sparse_post = None        # new hyperparameters
         self._n_in_post = len(x) + len(self._pending)
         self._needs_fit = False
@@ -367,12 +366,19 @@ class BayesOpt(Optimizer):
         x = np.asarray(self._xs)
         y = np.asarray(self._ys)
         bucket = gp.bucket_size(len(x) + len(self._pending) + extra)
-        post = gp.make_posterior(self._params, x, y, bucket=bucket)
-        for u in self._pending.values():
-            post = gp.append_lie(post, np.asarray(u, np.float32))
-        self._post = post
+        with tracing.span("opt.recondition", bucket=bucket):
+            post = gp.make_posterior(self._params, x, y, bucket=bucket)
+            self._post = self._fold_lies(post)
         self._n_in_post = len(x) + len(self._pending)
         self._needs_recondition = False
+
+    def _fold_lies(self, post):
+        """``post`` with every pending constant-liar lie folded in, one
+        rank-1 append each."""
+        with tracing.span("opt.fold_lies", lies=len(self._pending)):
+            for u in self._pending.values():
+                post = gp.append_lie(post, np.asarray(u, np.float32))
+        return post
 
     def maintenance_due(self) -> bool:
         """True when a deferred hyperparameter refit is owed — what the
@@ -469,23 +475,25 @@ class BayesOpt(Optimizer):
         n = int(n)
         if n <= 0 or n > gp.SELECT_PAD or not self.ask_spec_ready():
             return None
-        sparse = bool(speculative and self.sparse_eligible())
-        if sparse:
-            if (self._sparse_post is None
-                    or self._sparse_post.capacity - self._sparse_rows < n):
-                self._sparse_recondition(extra=n)
-            post = self._sparse_post
-        else:
-            if self._post is None or (self._needs_fit
-                                      and not (self.defer_fits
-                                               and self._params is not None)):
-                self._refit(extra=n)
-            elif (self._needs_fit or self._needs_recondition
-                    or self._free_slots() < n):
-                self._recondition(extra=n)
-            post = self._post
-            if post is None:
-                return None
+        with tracing.span("opt.ask", n=n, deferred=True):
+            sparse = bool(speculative and self.sparse_eligible())
+            if sparse:
+                if (self._sparse_post is None or
+                        self._sparse_post.capacity - self._sparse_rows < n):
+                    self._sparse_recondition(extra=n)
+                post = self._sparse_post
+            else:
+                if self._post is None or (
+                        self._needs_fit
+                        and not (self.defer_fits
+                                 and self._params is not None)):
+                    self._refit(extra=n)
+                elif (self._needs_fit or self._needs_recondition
+                        or self._free_slots() < n):
+                    self._recondition(extra=n)
+                post = self._post
+                if post is None:
+                    return None
         cand = self._candidates()
         best = float(max(self._ys))
 
@@ -530,37 +538,38 @@ class BayesOpt(Optimizer):
         n = int(n)
         if n <= 0:
             return []
-        if len(self._ys) < max(self.n_init, 2, len(self.space)):
-            return self._ask_random(n)
-        if speculative and self.sparse_eligible():
-            return self._ask_sparse(n)
-        if self._post is None or (self._needs_fit
-                                  and not (self.defer_fits
-                                           and self._params is not None)):
-            self._refit(extra=n)
-        elif (self._needs_fit or self._needs_recondition
-                or self._free_slots() < n):
-            # deferred-fit mode: fold the new observations exactly at the
-            # current hyperparameters; maintain() pays the fit later
-            self._recondition(extra=n)
-        if self._post is None:
-            return self._ask_random(n)
-        cand = self._candidates()
-        best_y = np.float32(max(self._ys))
-        picks, post = gp.select_batch(self._post, cand, best_y, n)
-        self._post = post
-        self._n_in_post += n
-        # the new exact-path lies are not in the cached sparse posterior:
-        # a later speculative refill must rebuild it or it could re-pick
-        # these very points
-        self._sparse_post = None
-        out = []
-        for j in np.asarray(picks):
-            u = np.asarray(cand[int(j)], float)
-            a = self.space.from_unit(u)
-            a[LIE_KEY] = self._new_lie(u)
-            out.append(a)
-        return out
+        with tracing.span("opt.ask", n=n):
+            if len(self._ys) < max(self.n_init, 2, len(self.space)):
+                return self._ask_random(n)
+            if speculative and self.sparse_eligible():
+                return self._ask_sparse(n)
+            if self._post is None or (self._needs_fit
+                                      and not (self.defer_fits
+                                               and self._params is not None)):
+                self._refit(extra=n)
+            elif (self._needs_fit or self._needs_recondition
+                    or self._free_slots() < n):
+                # deferred-fit mode: fold the new observations exactly at
+                # the current hyperparameters; maintain() pays the fit later
+                self._recondition(extra=n)
+            if self._post is None:
+                return self._ask_random(n)
+            cand = self._candidates()
+            best_y = np.float32(max(self._ys))
+            picks, post = gp.select_batch(self._post, cand, best_y, n)
+            self._post = post
+            self._n_in_post += n
+            # the new exact-path lies are not in the cached sparse
+            # posterior: a later speculative refill must rebuild it or it
+            # could re-pick these very points
+            self._sparse_post = None
+            out = []
+            for j in np.asarray(picks):
+                u = np.asarray(cand[int(j)], float)
+                a = self.space.from_unit(u)
+                a[LIE_KEY] = self._new_lie(u)
+                out.append(a)
+            return out
 
     # ------------------------------------------- sparse speculative ask
     def sparse_eligible(self) -> bool:
@@ -623,13 +632,11 @@ class BayesOpt(Optimizer):
         hyperparameters and fold the pending lies in — O(m³) with
         m <= the live ``_sparse_max`` budget, independent of history
         size."""
-        post, idx = gp.sparse_posterior(self._params, np.asarray(self._xs),
-                                        np.asarray(self._ys),
-                                        m=self._sparse_max,
-                                        extra=len(self._pending) + extra)
-        for u in self._pending.values():
-            post = gp.append_lie(post, np.asarray(u, np.float32))
-        self._sparse_post = post
+        with tracing.span("opt.recondition", sparse=True):
+            post, idx = gp.sparse_posterior(
+                self._params, np.asarray(self._xs), np.asarray(self._ys),
+                m=self._sparse_max, extra=len(self._pending) + extra)
+            self._sparse_post = self._fold_lies(post)
         self._sparse_m = len(idx)
         self._sparse_rows = len(idx) + len(self._pending)
 
@@ -696,10 +703,15 @@ class BayesOpt(Optimizer):
     def forget(self, assignment: Assignment) -> None:
         """Retire the lie of a suggestion that will never be observed
         (released / stopped), so it stops suppressing EI at that point."""
-        if self._retire_lie(Observation(assignment, None)):
-            self._sparse_post = None
-            if self._post is not None:
-                self._needs_recondition = True
+        with tracing.span("opt.forget"):
+            if self._retire_lie(Observation(assignment, None)):
+                self._sparse_post = None
+                if self._post is not None:
+                    self._needs_recondition = True
+
+    def tell(self, observations: Sequence[Observation]) -> None:
+        with tracing.span("opt.tell", n=len(observations)):
+            super().tell(observations)
 
     def _update(self, observations: Sequence[Observation]) -> None:
         if observations:

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.kernels import ops as _kops
 
 MIN_BUCKET = 16
@@ -305,6 +306,8 @@ def batched_fit(items, steps=150, bucket: Optional[int] = None) -> list:
             las[i] = np.asarray(params0.log_amp)
             lns[i] = np.asarray(params0.log_noise)
     # lanes k..kp-1 stay all-zero-mask (inert) with default params
+    tracing.annotate(h2d_bytes=st.nbytes + np.dtype(dtype).itemsize * sum(
+        a.size for a in (xs, ys, ms, lls, las, lns)))
     p0 = GPParams(jnp.asarray(lls, dtype), jnp.asarray(las, dtype),
                   jnp.asarray(lns, dtype))
     p = _fit_lanes(p0, jnp.asarray(xs, dtype), jnp.asarray(ys, dtype),
@@ -360,6 +363,8 @@ def fit_gp(x: np.ndarray, y: np.ndarray, steps: int = 150,
     y_mean = jnp.asarray(mean, dtype)
     y_std = jnp.asarray(std, dtype)
     xp, ynp, mask = _pad(x, (y_raw - mean) / std, b, dtype)
+    tracing.annotate(h2d_bytes=np.dtype(dtype).itemsize * (
+        xp.size + ynp.size + mask.size + 2))
     if params0 is None:
         p0 = GPParams(jnp.zeros(d, dtype) - 0.7, jnp.zeros((), dtype),
                       jnp.zeros((), dtype) - 2.0)
@@ -724,6 +729,8 @@ def batched_select(items, k_pad: int = SELECT_PAD) -> list:
         bests[i] = float(best)
         ks[i] = int(k)
     posts.extend(_inert_posterior(b, d, dtype) for _ in range(klp - kl))
+    tracing.annotate(h2d_bytes=ks.nbytes + np.dtype(dtype).itemsize * (
+        cands.size + bests.size))
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *posts)
     picks, posts_out = _select_lanes(
         stacked, jnp.asarray(cands, dtype), jnp.asarray(bests, dtype),
