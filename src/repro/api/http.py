@@ -50,6 +50,7 @@ from repro.api.transport import (FLUSH_DEADLINE_S, FLUSH_MAX_OPS,
                                  OP_REPORT, WriteBehind)
 from repro.core import tracing
 from repro.core.store import Store
+from repro.core.suggest.base import load_optimizers
 
 
 def _parse_path(path: str):
@@ -78,15 +79,19 @@ def _parse_path(path: str):
     return exp_id, action, None
 
 
-class _Handler(BaseHTTPRequestHandler):
+class JsonHandler(BaseHTTPRequestHandler):
+    """JSON over keep-alive HTTP/1.1: the request body read once, and
+    each reply sent in one write.
+
+    The status line, headers and body leave in one ``sendall``, the only
+    point between the route's return and the reply reaching the kernel
+    where the thread gives up the interpreter lock.  A thread the route
+    woke (a prefetch pump after a queue pop) then runs once the client's
+    bytes are sent, not between two writes of one reply.  TCP_NODELAY: a
+    reply longer than one segment ends in a short one, which Nagle would
+    hold until the client's delayed ACK (~40 ms)."""
     protocol_version = "HTTP/1.1"
-    # The response is written as two segments (headers, then body).  With
-    # Nagle on, the second small write sits in the kernel until the
-    # client's *delayed ACK* (~40 ms) releases it — which was the entire
-    # observed cost of the small-RPC hot path (report p50 ≈ 43 ms).
-    # TCP_NODELAY ships both segments immediately.
     disable_nagle_algorithm = True
-    backend: LocalClient = None           # set by serve_api
 
     # silence per-request stderr lines
     def log_message(self, fmt, *args):    # noqa: D102
@@ -113,8 +118,18 @@ class _Handler(BaseHTTPRequestHandler):
         self.send_response(status)
         self.send_header("Content-Type", "application/json")
         self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
-        self.wfile.write(body)
+        # what end_headers() would flush on its own, with the body behind
+        head = getattr(self, "_headers_buffer", [])   # none for HTTP/0.9
+        if head:
+            head.append(b"\r\n")
+        reply = b"".join(head + [body])
+        self._headers_buffer = []
+        with tracing.span("http.write", bytes=len(reply)):
+            self.wfile.write(reply)
+
+
+class _Handler(JsonHandler):
+    backend: LocalClient = None           # set by serve_api
 
     def _dispatch(self, method: str) -> None:
         self._body = None
@@ -196,6 +211,10 @@ class ApiServer:
     """Owns the HTTP listener and the backing ``LocalClient``."""
 
     def __init__(self, backend: LocalClient, host: str, port: int):
+        # before the first request: a first create would hold the
+        # backend's lock through seconds of imports, stalling every other
+        # request (and a fleet's load probes, which then time out)
+        load_optimizers()
         self.backend = backend
         handler = type("BoundHandler", (_Handler,), {"backend": backend})
         self._httpd = ThreadingHTTPServer((host, port), handler)

@@ -217,11 +217,16 @@ def register_stopping(name: str):
     return deco
 
 
-def make_optimizer(name: str, space: Space, seed: int = 0,
-                   **options) -> Optimizer:
-    # import for side-effect registration
+def load_optimizers() -> None:
+    """Import every optimizer module, which registers it by name.  The
+    first call takes seconds (the GP modules import the kernels)."""
     from repro.core.suggest import (bayesopt, evolution, grid, pso,  # noqa
                                     random_search, sobol)
+
+
+def make_optimizer(name: str, space: Space, seed: int = 0,
+                   **options) -> Optimizer:
+    load_optimizers()
     if name not in _REGISTRY:
         raise KeyError(f"unknown optimizer {name!r}; have {list(_REGISTRY)}")
     return _REGISTRY[name](space, seed=seed, **options)

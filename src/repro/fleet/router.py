@@ -175,6 +175,7 @@ class FleetClient(SuggestionClient):
         self._period = 1.0
         self._seq = 0
         self._lock = threading.RLock()
+        self._beat_lock = threading.Lock()
         self._stop = threading.Event()
         self._wake = threading.Event()
         self._hb_thread: Optional[threading.Thread] = None
@@ -475,12 +476,17 @@ class FleetClient(SuggestionClient):
         own; tests call it to drive liveness deterministically)."""
         if self._fault_plan is not None:
             self._fault_plan.gate(self.worker_id, "manager")
-        with self._lock:
-            self._seq += 1
-            req = HeartbeatRequest(worker_id=self.worker_id,
-                                   kind="scheduler",
-                                   holdings=self.holdings(), seq=self._seq)
-        resp = self._proxy.heartbeat(req)
+        # one beat in flight at a time: the manager keeps the holdings of
+        # the beat it applies last, so a beat that took its snapshot
+        # earlier must not reach it after a later one
+        with self._beat_lock:
+            with self._lock:
+                self._seq += 1
+                req = HeartbeatRequest(worker_id=self.worker_id,
+                                       kind="scheduler",
+                                       holdings=self.holdings(),
+                                       seq=self._seq)
+            resp = self._proxy.heartbeat(req)
         with self._lock:
             self._period = max(0.05, float(resp.period))
             self._last_beat = time.monotonic()

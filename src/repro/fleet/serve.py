@@ -22,45 +22,19 @@ over the *shared* store root) and/or externally-launched shard URLs.
 """
 from __future__ import annotations
 
-import json
 import threading
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+from http.server import ThreadingHTTPServer
 from typing import List, Optional, Sequence, Union
 
-from repro.api.http import ApiServer, serve_api
+from repro.api.http import ApiServer, JsonHandler, serve_api
 from repro.api.protocol import (ApiError, CreateExperiment, E_BAD_REQUEST,
                                 E_INTERNAL, HeartbeatRequest)
 from repro.core.store import Store
 from repro.fleet.manager import FleetManager
 
 
-class _FleetHandler(BaseHTTPRequestHandler):
-    protocol_version = "HTTP/1.1"
+class _FleetHandler(JsonHandler):
     manager: FleetManager = None            # set by FleetServer
-
-    def log_message(self, fmt, *args):      # noqa: D102
-        pass
-
-    def _take_body(self) -> bytes:
-        if getattr(self, "_body", None) is None:
-            n = int(self.headers.get("Content-Length") or 0)
-            self._body = self.rfile.read(n) if n else b""
-        return self._body
-
-    def _read_body(self) -> dict:
-        raw = self._take_body() or b"{}"
-        try:
-            return json.loads(raw)
-        except json.JSONDecodeError as e:
-            raise ApiError(E_BAD_REQUEST, f"invalid JSON body: {e}")
-
-    def _send(self, status: int, payload: dict) -> None:
-        body = json.dumps(payload).encode()
-        self.send_response(status)
-        self.send_header("Content-Type", "application/json")
-        self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
-        self.wfile.write(body)
 
     def _dispatch(self, method: str) -> None:
         self._body = None

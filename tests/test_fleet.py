@@ -406,6 +406,53 @@ def _spawn(script, **fmt):
     return proc, line
 
 
+def test_concurrent_beats_leave_the_manager_the_latest_holdings():
+    """The heartbeat thread and an explicit ``beat()`` can overlap.  The
+    manager keeps the holdings of the beat it applies last, so the beat
+    whose snapshot is older must not be applied after the newer one."""
+    manager, _ = _inproc_fleet(n=1)
+    client = FleetClient(manager, worker_id="w", heartbeat=False)
+    first_in, release = threading.Event(), threading.Event()
+    real = manager.heartbeat
+
+    def slow_first(req, on_dead=None):
+        if not first_in.is_set():
+            first_in.set()
+            release.wait(5)
+        return real(req, on_dead)
+
+    manager.heartbeat = slow_first
+    old = threading.Thread(target=client.beat)
+    old.start()
+    assert first_in.wait(5)
+    with client._lock:                      # a suggestion taken meanwhile
+        client._holdings["exp-a"] = {"s-1"}
+    new = threading.Thread(target=client.beat)
+    new.start()
+    time.sleep(0.2)
+    release.set()
+    old.join(5)
+    new.join(5)
+    assert manager.registry.get("w").holdings == {"exp-a": ["s-1"]}
+    client.close()
+
+
+def test_api_server_loads_optimizers_before_its_first_request():
+    """Loading the optimizer modules takes seconds; a shard's first create
+    would hold the backend's lock through it, and the fleet's load probes
+    would time out and declare a live shard dead."""
+    code = ("import sys, tempfile\n"
+            "from repro.api import serve_api\n"
+            "before = 'repro.core.suggest.bayesopt' in sys.modules\n"
+            "serve_api(tempfile.mkdtemp())\n"
+            "print(before, 'repro.core.suggest.bayesopt' in sys.modules)\n")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [SRC, os.environ.get("PYTHONPATH", "")]), JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], env=env, timeout=120,
+                         capture_output=True, text=True)
+    assert out.stdout.split() == ["False", "True"], out.stderr
+
+
 def test_kill9_scheduler_requeues_within_two_periods():
     """Acceptance: kill −9 a scheduler holding pending suggestions under
     k=8-experiment load — every held suggestion is requeued and served to
