@@ -1019,6 +1019,10 @@ class LocalClient(SuggestionClient):
                 # None until a fit was actually submitted — a monitoring
                 # read must not spawn the executor's worker pool
                 pump_stats["executor"] = pipeline.executor_snapshot()
+                # the process's shape-warming counters (loaded with the
+                # optimizers, so the import costs nothing here)
+                from repro.core.suggest import gp
+                pump_stats["prewarm"] = gp.prewarm_stats()
             return StatusResponse(
                 exp_id=exp_id, state=st.get("state", "pending"),
                 name=state.cfg.name, budget=state.cfg.budget,

@@ -360,10 +360,10 @@ class StatusResponse:
     of pre-computed suggestions currently warm in the prefetch queue, and
     ``pump`` carries the pump's counters (hits, misses, coalesced,
     invalidated, prefilled, sparse_prefilled, prewarmed, alive, depth —
-    plus, for live experiments, the optimizer's ``refit`` schedule and
-    the shared fit executor's ``executor`` counters, API.md §Posterior
-    approximation & refit scheduling) or ``None`` for a non-live
-    experiment.
+    plus, for live experiments, the optimizer's ``refit`` schedule, the
+    shared fit executor's ``executor`` counters, API.md §Posterior
+    approximation & refit scheduling, and the process's shape-warming
+    ``prewarm`` counters) or ``None`` for a non-live experiment.
 
     ``epoch`` is the serving shard's ownership epoch for the experiment
     (``[term, seq]``, additive v1 field); ``transport`` carries the

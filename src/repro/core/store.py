@@ -86,6 +86,13 @@ class Store:
         self._log_handles: "collections.OrderedDict[pathlib.Path, TextIO]" \
             = collections.OrderedDict()
 
+    def _tmp(self, p: pathlib.Path) -> pathlib.Path:
+        """The temporary file this store writes before replacing ``p``:
+        named per process and per store, since other stores (in this
+        process or another sharing the root) may replace ``p`` at the same
+        time, and a shared name lets one rename the other's file away."""
+        return p.with_name(f"{p.stem}.{os.getpid()}-{id(self)}.tmp")
+
     # ----------------------------------------------------------- experiments
     def exp_dir(self, exp_id: str) -> pathlib.Path:
         return self.root / "experiments" / exp_id
@@ -103,7 +110,7 @@ class Store:
 
     def set_status(self, exp_id: str, status: Dict[str, Any]) -> None:
         p = self.exp_dir(exp_id) / "status.json"
-        tmp = p.with_suffix(".tmp")
+        tmp = self._tmp(p)
         text = json.dumps(status, indent=1)
         with self._lock:
             tmp.write_text(text)
@@ -187,7 +194,7 @@ class Store:
             if epoch < cur:
                 raise FencedError(exp_id, epoch, cur, cur_owner)
             p = self.fence_path(exp_id)
-            tmp = p.with_suffix(".tmp")
+            tmp = self._tmp(p)
             text = json.dumps({"epoch": list(epoch), "owner": owner,
                                "time": time.time()})
             tmp.write_text(text)
@@ -222,7 +229,7 @@ class Store:
 
     def write_fleet_state(self, name: str, state: Dict[str, Any]) -> None:
         p = self.fleet_path(f"{name}.json")
-        tmp = p.with_suffix(".tmp")
+        tmp = self._tmp(p)
         with self._lock:
             tmp.write_text(json.dumps(state, indent=1))
             os.replace(tmp, p)  # atomic

@@ -280,6 +280,9 @@ class BayesOpt(Optimizer):
         pad up to ``batch``).  Touches no optimizer state — safe to call
         from a background thread while ``ask``/``tell`` run elsewhere,
         since jitted functions cache per shape signature process-wide.
+        ``gp.prewarm_bucket`` warms each signature once per process, so
+        another optimizer over the same space runs nothing here; the
+        return value still counts the buckets this optimizer asked for.
 
         The solo ``fit_lanes=(1,)`` executor variant is warmed too:
         since the FitExecutor routes every refit through ``batched_fit``,

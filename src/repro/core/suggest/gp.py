@@ -35,7 +35,9 @@ Hot-path design (the suggestion service calls this once per `ask` batch):
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -435,6 +437,52 @@ def sparse_posterior(params: GPParams, x: np.ndarray, y: np.ndarray,
 
 
 # ---------------------------------------------------------------- prewarm
+#: Program signatures warmed in this process (each with its dtype), the
+#: lock each is warmed under, and what became of every request for one:
+#: ``warmed`` ran its programs, ``skipped`` found it warmed and ran
+#: nothing, and ``waited`` counts the skips that first waited for another
+#: thread's warm of the same signature to finish.
+_WARMED: set = set()
+_WARM_LOCKS: dict = {}
+_WARM_STATS = {"warmed": 0, "skipped": 0, "waited": 0}
+_WARM_LOCK = threading.Lock()
+
+
+def prewarm_stats() -> dict:
+    """This process's ``prewarm_bucket`` counters, one count per program
+    signature asked for: ``warmed``, ``skipped`` and, of the skips,
+    ``waited``."""
+    with _WARM_LOCK:
+        return dict(_WARM_STATS)
+
+
+@contextlib.contextmanager
+def _warming(tally: dict, *sig):
+    """Yield True to the one caller in the process that must warm the
+    program signature ``sig`` (at the current dtype), False to every
+    later one.  The warmer holds the signature's own lock until its block
+    ends, so a caller arriving meanwhile waits, then skips: it never goes
+    on to dispatch a shape still being compiled.  A warm that raises
+    leaves the signature to the next caller."""
+    key = sig + (np.dtype(_dtype()).name,)
+    with _WARM_LOCK:
+        lock = _WARM_LOCKS.setdefault(key, threading.Lock())
+    waited = not lock.acquire(blocking=False)
+    if waited:
+        lock.acquire()
+    try:
+        todo = key not in _WARMED
+        yield todo
+        _WARMED.add(key)
+        outcome = "warmed" if todo else "skipped"
+        with _WARM_LOCK:
+            _WARM_STATS[outcome] += 1
+            _WARM_STATS["waited"] += waited and not todo
+        tally[outcome] += 1
+    finally:
+        lock.release()
+
+
 def prewarm_bucket(d: int, bucket: int, fit_steps=(), k_pads=(),
                    n_cand: int = 64, fit_lanes=(), select_lanes=()) -> None:
     """Compile every jitted kernel on the ask path for one bucket shape,
@@ -443,18 +491,24 @@ def prewarm_bucket(d: int, bucket: int, fit_steps=(), k_pads=(),
     the q-EI scan (one variant per ``k_pads`` entry, at the real candidate
     pool size ``n_cand``).  XLA caches compilations per shape signature,
     so calling this off the request path moves the first-touch compile
-    cost (~0.7 s per bucket on the dev container) out of ``ask`` — the
-    dominant term in the cold `gp/h10` and bucket-crossing `gp_batch8`
-    latencies.  Idempotent: re-running against warm caches costs only the
-    (small) dummy-data compute.
+    cost out of ``ask``.
 
-    ``fit_lanes`` is the k-pad ladder of the batched executor path
+    Each program signature is warmed once per process: ``("fit", d,
+    bucket, steps)``, ``("fit_lanes", d, bucket, steps, lane_pad)``,
+    ``("posterior", d, bucket)``, ``("append", d, bucket)``,
+    ``("select", d, bucket, k_pad, n_cand)`` and ``("select_lanes", d,
+    bucket, lane_pad, n_cand)``, each at the current dtype.  A later
+    request for a warmed signature runs nothing, so the many experiments
+    of one space that a service starts together warm the grid once
+    between them; ``prewarm_stats()`` counts the outcomes, and a
+    ``gp.prewarm`` span (attrs ``bucket``, ``warmed``, ``skipped``)
+    wraps each call.
+
+    ``fit_lanes`` is the lane-pad ladder of the batched executor path
     (ISSUE 8): for each lane count the ``_fit_lanes`` variant is
     compiled at every ``fit_steps`` entry, so a fleet's first batched
     refit dispatch doesn't pay its (bucket, steps, lane-pad) compile
-    under load.  Off by default — batched dispatches already run off
-    the request path, so lazy first-touch compiles only delay one
-    install.
+    under load.
 
     ``select_lanes`` is the analogous lane-pad ladder of the batched
     *ask* path (ISSUE 10): for each lane count the ``_select_lanes``
@@ -465,24 +519,52 @@ def prewarm_bucket(d: int, bucket: int, fit_steps=(), k_pads=(),
     x[1] = 0.5
     y = np.array([0.0, 1.0], np.float64)
     post = None
-    for s in sorted({int(s) for s in fit_steps}):
-        post = fit_gp(x, y, steps=s, bucket=bucket)
-        for lanes in sorted({lane_pad(int(kp)) for kp in fit_lanes}):
-            batched_fit([(x, y, None)] * lanes, steps=s, bucket=bucket)
-    if post is None:
-        post = make_posterior(
-            GPParams(jnp.zeros(d, _dtype()), jnp.zeros(()), jnp.zeros(())),
-            x, y, bucket=bucket)
-    # match the real call signatures exactly (host numpy float32 inputs)
-    append_point(post, np.asarray(x[0], np.float32), np.float32(0.5))
-    append_lie(post, np.asarray(x[0], np.float32))
-    cand = np.zeros((n_cand, d), np.float32)
-    for kp in sorted({int(k) for k in k_pads}):
-        if kp + 2 <= bucket:    # the scan needs kp free padded slots
-            select_batch(post, cand, np.float32(1.0), kp)
-    if SELECT_PAD + 2 <= bucket:
-        for lanes in sorted({lane_pad(int(s)) for s in select_lanes}):
-            batched_select([(post, cand, np.float32(1.0), 1)] * lanes)
+    tally = {"warmed": 0, "skipped": 0}
+
+    def posterior():
+        nonlocal post
+        if post is None:
+            post = make_posterior(
+                GPParams(jnp.zeros(d, _dtype()), jnp.zeros(()),
+                         jnp.zeros(())), x, y, bucket=bucket)
+        return post
+
+    with tracing.span("gp.prewarm", bucket=int(bucket)) as sp:
+        for s in sorted({int(s) for s in fit_steps}):
+            with _warming(tally, "fit", d, bucket, s) as todo:
+                if todo:
+                    post = fit_gp(x, y, steps=s, bucket=bucket)
+            for lanes in sorted({lane_pad(int(kp)) for kp in fit_lanes}):
+                with _warming(tally, "fit_lanes", d, bucket, s,
+                              lanes) as todo:
+                    if todo:
+                        batched_fit([(x, y, None)] * lanes, steps=s,
+                                    bucket=bucket)
+        with _warming(tally, "posterior", d, bucket) as todo:
+            if todo:
+                posterior()
+        with _warming(tally, "append", d, bucket) as todo:
+            # match the real call signatures exactly (host numpy float32)
+            if todo:
+                append_point(posterior(), np.asarray(x[0], np.float32),
+                             np.float32(0.5))
+                append_lie(posterior(), np.asarray(x[0], np.float32))
+        cand = np.zeros((n_cand, d), np.float32)
+        for kp in sorted({int(k) for k in k_pads}):
+            if kp + 2 <= bucket:    # the scan needs kp free padded slots
+                with _warming(tally, "select", d, bucket, kp,
+                              n_cand) as todo:
+                    if todo:
+                        select_batch(posterior(), cand, np.float32(1.0), kp)
+        if SELECT_PAD + 2 <= bucket:
+            for lanes in sorted({lane_pad(int(s)) for s in select_lanes}):
+                with _warming(tally, "select_lanes", d, bucket, lanes,
+                              n_cand) as todo:
+                    if todo:
+                        batched_select(
+                            [(posterior(), cand, np.float32(1.0), 1)]
+                            * lanes)
+        sp.set(**tally)
 
 
 # ---------------------------------------------------------------- queries

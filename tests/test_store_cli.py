@@ -1,7 +1,9 @@
 """Store persistence + the six CLI verbs (paper §3.1)."""
 import json
 import pathlib
+import sys
 import tempfile
+import threading
 
 import pytest
 import yaml
@@ -31,6 +33,40 @@ def test_logs_aggregated_per_experiment():
     store.append_log("e1", "t2", "hello from t2")
     lines = list(store.iter_logs("e1"))
     assert "[t1] hello from t1" in lines and "[t2] hello from t2" in lines
+
+
+def test_two_stores_on_one_root_rewrite_one_status_concurrently():
+    """Stores sharing a root (an in-process fleet's shards) each write a
+    temporary file of their own before the rename, so neither renames the
+    other's away and every rewrite lands whole."""
+    root = tempfile.mkdtemp()
+    cfg = ExperimentConfig(name="x", space=Space([Param("a", "double", 0, 1)]))
+    stores = [Store(root), Store(root)]
+    stores[0].create_experiment("e1", cfg)
+    errors = []
+
+    def writer(store, tag):
+        try:
+            for i in range(300):
+                store.set_status("e1", {"writer": tag, "i": i})
+        except OSError as e:
+            errors.append(e)
+    threads = [threading.Thread(target=writer, args=(s, n))
+               for n, s in enumerate(stores)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        sys.setswitchinterval(interval)
+    assert errors == []
+    assert Store(root).get_status("e1")["i"] == 299
+    exp_dir = pathlib.Path(root) / "experiments" / "e1"
+    assert sorted(p.name for p in exp_dir.iterdir() if p.is_file()) == [
+        "config.json", "status.json"]
 
 
 # --- CLI ------------------------------------------------------------------
