@@ -1,11 +1,10 @@
 #!/usr/bin/env bash
-# CI entrypoint: tier-1 correctness, then the tier-2 gate (multi-client
-# contention tests + perf check).
+# CI entrypoint: tier-1 correctness, then tier 2 (multi-client
+# contention tests and chaos fault-injection tests).
 #
 #   scripts/ci.sh            # non-zero exit on any failure
 #
-# ROADMAP.md documents both tiers.  Run on an otherwise idle machine:
-# CPU contention alone inflates perf rows ~2x (the gate tolerates 3x).
+# Speed is measured on the chip by bench/ (BENCHMARK.json, PERF.md).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
@@ -28,20 +27,3 @@ echo "== tier-2: chaos fault-injection tests =="
 # mid-response connection kills
 REPRO_CHAOS=1 python -m pytest -q -m chaos \
     tests/test_fencing.py tests/test_transport.py
-
-echo "== tier-2: perf gate =="
-# --strict: a quick-sweep row missing from the committed BENCH_suggest.json
-# fails CI (stale baseline after a bench rename/addition).  Gated rows
-# include the fleet SLO (bench_fleet/suggest/k8c4: 8 experiments x 4
-# clients through the HTTP router, gated on p50 — see API.md §Fleet).
-bench_out=$(mktemp)
-if ! python scripts/bench_check.py --strict | tee "$bench_out"; then
-    echo
-    echo "== bench delta summary (worst rows vs baseline) =="
-    grep -E "x[0-9]+\.[0-9]+" "$bench_out" \
-        | sed -E 's/^(.*) x([0-9]+\.[0-9]+)(.*)$/\2 \1 x\2\3/' \
-        | sort -rn | head -10 | cut -d' ' -f2-
-    rm -f "$bench_out"
-    exit 1
-fi
-rm -f "$bench_out"

@@ -131,7 +131,7 @@ TEMPLATE = """# EXPERIMENTS — Orchestrate-JAX
 All artifacts are reproducible:
 - dry-run cells: `PYTHONPATH=src python -m repro.launch.dryrun --all` (JSON per
   cell under `results/dryrun*/`, gzipped compiled HLO under `results/*hlo*/`)
-- benchmarks: `PYTHONPATH=src python -m benchmarks.run`
+- benchmark (on the chip): `python3 bench/run.py` (cells in BENCHMARK.json)
 - tests: `PYTHONPATH=src pytest tests/`
 
 Hardware model (TPU v5e target; this container is CPU-only so the dry-run
@@ -298,23 +298,16 @@ logs UX, failed-observation accounting).  Reproduced:
 
 - `examples/hpo_cnn.py --paper` runs 300 evals / 15 parallel of the same
   CNN shape (synthetic stand-in for GTSRB; offline container).
-- `benchmarks/bench_parallel.py`: wall-clock speedup of the scheduler at
-  1/5/15 workers under lognormal trial durations — near-linear (see
-  bench_output.txt; efficiency ≥0.9 at 15 workers with 60 trials).
-- `benchmarks/bench_scheduler.py`: straggler speculation is measured under
-  saturated slots (budget ≫ parallel), where it correctly does NOT fire
-  mid-experiment (no free slot to speculate into) — wall-clock parity in
-  bench_output.txt; the mechanism itself (3× median detection, first
-  finisher wins, loser cancelled) is asserted in
+- Straggler speculation (3× median detection, first finisher wins, loser
+  cancelled) is asserted in
   `tests/test_scheduler.py::test_straggler_speculation_wins` (beats a 4 s
   straggler tail by >2 s).
 - Fig. 4 UX (status screen, aggregated `logs --follow`, failed-observation
   counts) is reproduced by the CLI (`tests/test_store_cli.py` asserts the
   full lifecycle, including cluster-destroy ≠ experiment-delete).
-- `benchmarks/bench_population.py`: the beyond-paper vmap population
-  executor trains 8 trials in one program ~2× faster than sequentially even
-  on CPU (on TPU the win is the MXU batching; equivalence to sequential
-  training is exact — `tests/test_population.py`, diff < 1e-5).
+- The beyond-paper vmap population executor trains 8 trials in one
+  program; equivalence to sequential training is exact
+  (`tests/test_population.py`, diff < 1e-5).
 """
 
 

@@ -102,22 +102,14 @@ class FitSpec:
 
 def run_fit_lanes(specs: Sequence[FitSpec]):
     """FitExecutor lane runner: fit every spec (all sharing one shape
-    bucket) in one ``gp.batched_fit`` dispatch — or the ordinary
-    ``fit_gp`` path for a single lane, so a lone refit reuses the
-    per-bucket ``_fit`` compiles ``prewarm`` already paid for.  Mixed
-    per-lane step counts are fine: the batched fit runs a masked
-    ``max(steps)`` loop that freezes each lane at its own budget.
-    Returns (list of fitted GPParams, total wall seconds)."""
+    bucket) in one ``gp.batched_fit`` dispatch; a lone spec is its
+    one-lane dispatch.  Mixed per-lane step counts are fine: the batched
+    fit runs a masked ``max(steps)`` loop that freezes each lane at its
+    own budget.  Returns (list of fitted GPParams, total wall seconds)."""
     t0 = time.perf_counter()
-    if len(specs) == 1:
-        s = specs[0]
-        post = gp.fit_gp(s.x, s.y, steps=s.steps, params0=s.params0,
-                         bucket=s.bucket)
-        out = [post.params]
-    else:
-        out = gp.batched_fit([(s.x, s.y, s.params0) for s in specs],
-                             steps=[s.steps for s in specs],
-                             bucket=specs[0].bucket)
+    out = gp.batched_fit([(s.x, s.y, s.params0) for s in specs],
+                         steps=[s.steps for s in specs],
+                         bucket=specs[0].bucket)
     return out, time.perf_counter() - t0
 
 
@@ -284,16 +276,12 @@ class BayesOpt(Optimizer):
         another optimizer over the same space runs nothing here; the
         return value still counts the buckets this optimizer asked for.
 
-        The solo ``fit_lanes=(1,)`` executor variant is warmed too:
-        since the FitExecutor routes every refit through ``batched_fit``,
-        the lane-pad-1 compile otherwise lands mid-run — off the request
-        path, but on a saturated box it still stalls in-flight requests
-        for the compile's duration.  Multi-lane pads stay lazy (they only
-        occur when experiments co-batch).  The batched-ask scan is warmed
-        at ``select_lanes=(1, 2)`` (ISSUE 10): every executor refill
-        dispatch runs through ``batched_select``, so lane pads 1 and 2 —
-        the overwhelmingly common co-batch widths — must never compile
-        mid-run; wider pads stay lazy for the same reason as fit lanes."""
+        A lone executor fit runs the ``_fit`` warmed here at the same
+        step counts, and a lone refill ask the q-EI scan at
+        ``gp.SELECT_PAD``.  ``select_lanes=(1, 2)`` warms that scan and
+        lane pad 2, the overwhelmingly common co-batch widths, so neither
+        compiles mid-run; wider select pads and every multi-lane fit pad
+        stay lazy (they only occur when experiments co-batch)."""
         target = gp.bucket_size(max(1, int(max_history)))
         k_pads, kp = [], 1
         pad_max = 1 << max(0, int(batch) - 1).bit_length()
@@ -312,12 +300,13 @@ class BayesOpt(Optimizer):
                                   fit_steps=(self.fit_steps,
                                              self._warm_steps_at(b // 2),
                                              self._warm_steps_at(b)),
-                                  k_pads=k_pads, n_cand=m, fit_lanes=(1,),
+                                  k_pads=k_pads, n_cand=m,
                                   select_lanes=(1, 2))
                 warmed += 1
             b *= 2
         self._prewarmed = max(self._prewarmed, target)
         return warmed
+
     def _new_lie(self, u: np.ndarray) -> str:
         self._lie_seq += 1
         key = f"lie-{self._lie_nonce}-{self._lie_seq:05d}"

@@ -16,9 +16,12 @@ Hot-path design (the suggestion service calls this once per `ask` batch):
   into a free padded slot with a bordered-Cholesky update: O(n²) per point
   instead of a fresh O(steps·n³) hyperparameter fit.  Constant-liar
   batching in ``BayesOpt`` rides on this.
-* **Batched q-EI selection** — ``select_batch`` picks a whole batch of
-  suggestions in one jitted scan (EI argmax → fold lie → repeat), so the
-  per-point Python/dispatch overhead vanishes.
+* **One fit and one selection algorithm, over lanes** — the Adam fit
+  (``_adam_lanes``) and the q-EI scan (``_qei_lanes``: EI argmax →
+  fold lie → repeat, a whole batch in one jitted scan) each carry a
+  leading lane axis of experiments, so several experiments share one dispatch
+  (``_fit_lanes``, ``_select_lanes``).  A lone fit or select is a
+  one-lane dispatch of the same algorithm (``_fit``, ``_select_scan``).
 * **Warm starts** — ``fit_gp(..., params0=...)`` resumes Adam from the
   previous optimum so converged posteriors need far fewer steps.
 * **Sparse speculative posterior** — ``sparse_posterior`` builds an exact
@@ -59,7 +62,7 @@ FIT_LANES_MAX = 32
 #: pick count traced, so lanes wanting different batch sizes still share
 #: one compile per (bucket, SELECT_PAD, lane-pad) — and the service's
 #: refill chunk (``pipeline.ASK_CHUNK``) is sized to never exceed it.
-#: The solo ``select_batch`` path keeps its natural per-k pads.
+#: ``select_batch`` pads its scan to ``lane_pad(k)`` instead.
 SELECT_PAD = 8
 
 #: Cap on the subset-of-data design of the sparse speculative posterior.
@@ -141,74 +144,28 @@ def _masked_cov(params: GPParams, x: jnp.ndarray,
     return k * mm + jnp.diag(1.0 - mask)
 
 
-@jax.jit
-def neg_mll(params: GPParams, x: jnp.ndarray, y: jnp.ndarray,
-            mask: jnp.ndarray) -> jnp.ndarray:
-    """Exact negative log marginal likelihood over the masked rows only:
-    identity padding contributes log(1)=0 to the determinant and 0 to the
-    quadratic form, so the value is independent of the bucket size."""
-    k = _masked_cov(params, x, mask)
-    chol = jnp.linalg.cholesky(k)
-    ym = y * mask
-    alpha = jax.scipy.linalg.cho_solve((chol, True), ym)
-    return (0.5 * _dot(ym, alpha)
-            + jnp.sum(jnp.log(jnp.diagonal(chol)))
-            + 0.5 * jnp.sum(mask) * jnp.log(2 * jnp.pi))
-
-
-@functools.partial(jax.jit, static_argnames=("steps",))
-def _fit(params0: GPParams, x, y, mask, steps: int = 150, lr: float = 0.05):
-    """Adam on the negative MLL."""
-    def adam_step(carry, _):
-        p, m, v, t = carry
-        g = jax.grad(neg_mll)(p, x, y, mask)
-        t = t + 1
-        m = jax.tree.map(lambda mm, gg: 0.9 * mm + 0.1 * gg, m, g)
-        v = jax.tree.map(lambda vv, gg: 0.999 * vv + 0.001 * gg * gg, v, g)
-        mh = jax.tree.map(lambda mm: mm / (1 - 0.9 ** t), m)
-        vh = jax.tree.map(lambda vv: vv / (1 - 0.999 ** t), v)
-        p = jax.tree.map(lambda pp, mm, vv: pp - lr * mm / (jnp.sqrt(vv) + 1e-8),
-                         p, mh, vh)
-        # clamp to sane ranges to keep the Cholesky healthy; reject any
-        # step that went NaN (singular K during the line search)
-        p = GPParams(jnp.clip(p.log_ls, -3.0, 1.5),
-                     jnp.clip(p.log_amp, -3.0, 2.0),
-                     jnp.clip(p.log_noise, -5.0, 1.0))
-        ok = jnp.all(jnp.stack([jnp.all(jnp.isfinite(x))
-                                for x in jax.tree.leaves(p)]))
-        prev = carry[0]
-        p = jax.tree.map(lambda new, old: jnp.where(ok, new, old), p, prev)
-        return (p, m, v, t), None
-
-    zeros = jax.tree.map(jnp.zeros_like, params0)
-    (p, _, _, _), _ = jax.lax.scan(
-        adam_step, (params0, zeros, zeros, jnp.zeros((), jnp.int32)),
-        None, length=steps)
-    return p
-
-
 def lane_pad(k: int) -> int:
-    """Smallest power of two >= k — the lane-count pad of ``batched_fit``
-    and ``batched_select`` (one ``_fit_lanes`` compile per
-    (bucket, max-steps, lane-pad) triple, one ``_select_lanes`` compile
-    per (bucket, k-pad, lane-pad) triple)."""
+    """Smallest power of two >= k: the lane-count pad of ``batched_fit``
+    and ``batched_select``, and the scan-length pad of ``select_batch``.
+    A one-lane dispatch runs the one-lane view (``_fit``,
+    ``_select_scan``); wider pads run ``_fit_lanes`` (one compile per
+    (bucket, max-steps, lane-pad)) and ``_select_lanes`` (one per
+    (bucket, k-pad, lane-pad))."""
     return 1 << max(0, int(k) - 1).bit_length()
 
 
-@functools.partial(jax.jit, static_argnames=("max_steps",))
-def _fit_lanes(params0: GPParams, x, y, mask, steps, max_steps: int = 150,
-               lr: float = 0.05):
-    """Batched ``_fit``: every GPParams leaf and data array carries a
-    leading lane axis (k experiments), and one Adam loop advances all
-    lanes together — the per-lane gradients come from one batched
-    dispatch (``ops.gp_fit_grads``: the fused Pallas neg-MLL's analytic
-    custom_vjp on TPU, the GEMM-rich analytic adjoint from kernels/ref
-    here on CPU — the latter is why a lane costs less than a serial
-    autodiff fit even on one core).  Lanes are independent: the adjoint
-    is computed per lane, and the NaN-reject check is per-lane, so one
-    ill-conditioned experiment can't stall its batch peers.
-    All-zero-mask lanes (the lane padding) see an identity covariance —
-    zero gradient, parameters inert.
+def _adam_lanes(params0: GPParams, x, y, mask, steps, max_steps: int,
+                lr: float):
+    """Adam on the exact masked negative MLL, for k experiments at once:
+    every GPParams leaf and data array carries a leading lane axis, and
+    one Adam loop advances all lanes together — the per-lane gradients
+    come from one batched dispatch (``ops.gp_fit_grads``: the fused
+    Pallas neg-MLL's analytic custom_vjp on TPU, the GEMM-rich analytic
+    adjoint from kernels/ref on other backends).  Lanes are independent:
+    the adjoint is computed per lane, and the NaN-reject check is
+    per-lane, so one ill-conditioned experiment can't stall its batch
+    peers.  All-zero-mask lanes (the lane padding) see an identity
+    covariance — zero gradient, parameters inert.
 
     ``steps`` is a traced (k,) int32 of per-lane step budgets and
     ``max_steps`` the static scan length (>= every entry): the loop runs
@@ -217,8 +174,8 @@ def _fit_lanes(params0: GPParams, x, y, mask, steps, max_steps: int = 150,
     different rungs of the adaptive warm-step ladder therefore share one
     dispatch, and because every live lane sees the identical global Adam
     step index ``t``, a lane frozen at ``steps[i]`` holds exactly the
-    parameters a solo ``_fit_lanes`` run of length ``steps[i]`` would
-    produce — bit-identical, not merely close."""
+    parameters a uniform run of length ``steps[i]`` at the same lane pad
+    would produce — bit-identical, not merely close."""
     def adam_step(carry, _):
         p, m, v, t = carry
         g = GPParams(*_kops.gp_fit_grads(p.log_ls, p.log_amp,
@@ -249,23 +206,82 @@ def _fit_lanes(params0: GPParams, x, y, mask, steps, max_steps: int = 150,
     return p
 
 
+@functools.partial(jax.jit, static_argnames=("max_steps",))
+def _fit_lanes(params0: GPParams, x, y, mask, steps, max_steps: int = 150,
+               lr: float = 0.05):
+    """``_adam_lanes`` over a lane axis of k experiments."""
+    return _adam_lanes(params0, x, y, mask, steps, max_steps, lr)
+
+
+@functools.partial(jax.jit, static_argnames=("steps",))
+def _fit(params0: GPParams, x, y, mask, steps: int = 150, lr: float = 0.05):
+    """``_adam_lanes`` at one lane, for ``steps`` Adam steps.  The lane
+    axis is added and stripped inside the program, so a lone fit is one
+    dispatch of the lane algorithm."""
+    p = _adam_lanes(jax.tree.map(lambda a: a[None], params0), x[None],
+                    y[None], mask[None], jnp.full((1,), steps, jnp.int32),
+                    steps, lr)
+    return jax.tree.map(lambda a: a[0], p)
+
+
+def _fit_inputs(items, b: int, kp: int):
+    """The host side of a fit dispatch: float64 buffers with a leading
+    lane axis of ``kp``, one lane per ``(x, y, params0)`` item — its
+    history normalized and padded to bucket ``b`` under a 0/1 mask, and
+    its warm start or the default one.  Lanes past the items keep an
+    all-zero mask (inert) and the default start.  Normalizing and
+    padding on the host keeps every device program at the bucket's
+    shape.  Returns ``((params0, x, y, mask), [(mean, std) per item])``."""
+    d = np.asarray(items[0][0]).shape[1]
+    xs = np.zeros((kp, b, d), np.float64)
+    ys = np.zeros((kp, b), np.float64)
+    ms = np.zeros((kp, b), np.float64)
+    p0 = GPParams(np.full((kp, d), -0.7), np.zeros((kp,)),
+                  np.full((kp,), -2.0))
+    norms = []
+    for i, (x, y, params0) in enumerate(items):
+        x = np.asarray(x, np.float64)
+        y_raw = np.asarray(y, np.float64)
+        n = x.shape[0]
+        if b < n:
+            raise ValueError(f"bucket {b} smaller than training set {n}")
+        mean = float(np.mean(y_raw))
+        std = max(float(np.std(y_raw)), 1e-6)
+        xs[i, :n] = x
+        ys[i, :n] = (y_raw - mean) / std
+        ms[i, :n] = 1.0
+        if params0 is not None:
+            for buf, leaf in zip(p0, params0):
+                buf[i] = np.asarray(leaf)
+        norms.append((mean, std))
+    return (p0, xs, ys, ms), norms
+
+
+def _lane0(dtype, *arrays):
+    """Lane 0 of host lane buffers (arrays or GPParams of arrays), each
+    put on the device once."""
+    return [jax.tree.map(lambda a: jnp.asarray(a[0], dtype), a)
+            for a in arrays]
+
+
 def batched_fit(items, steps=150, bucket: Optional[int] = None) -> list:
-    """Fit k experiments' GP hyperparameters in ONE vmap'd dispatch.
+    """Fit k experiments' GP hyperparameters in ONE dispatch.
 
     ``items`` is a sequence of ``(x, y, params0)`` triples — x (n,d) in
     the unit cube, y raw objective, params0 a warm start or None — all
     sharing one shape ``bucket`` (default: smallest bucket fitting the
     largest history).  Each lane is normalized and padded exactly as
-    ``fit_gp`` would, stacked along a leading lane axis, and the lane
-    count is padded to the next power of two with inert all-zero-mask
-    lanes, so XLA compiles once per (bucket, max-steps, lane-pad) triple.
+    ``fit_gp`` does.  One item runs ``_fit``, the executable ``fit_gp``
+    runs; k > 1 items are stacked along a leading lane axis, the lane
+    count padded to the next power of two with inert all-zero-mask
+    lanes, and run by ``_fit_lanes``, which compiles once per (bucket,
+    max-steps, lane-pad) triple.
 
     ``steps`` is an int (every lane) or a per-lane sequence: lanes on
     different adaptive-ladder step counts run inside one masked loop of
-    ``max(steps)`` iterations (see ``_fit_lanes``) — each lane's result
-    is bit-identical to a solo fit at its own step count.  Returns a
-    list of k fitted ``GPParams`` (install with ``make_posterior`` /
-    the optimizer's recondition, as usual)."""
+    ``max(steps)`` iterations (see ``_fit_lanes``).  Returns a list of k
+    fitted ``GPParams`` (install with ``make_posterior`` / the
+    optimizer's recondition, as usual)."""
     if not items:
         return []
     if len(items) > FIT_LANES_MAX:
@@ -274,8 +290,6 @@ def batched_fit(items, steps=150, bucket: Optional[int] = None) -> list:
     dtype = _dtype()
     b = bucket if bucket is not None else bucket_size(
         max(np.asarray(x).shape[0] for x, _, _ in items))
-    b = int(b)
-    d = np.asarray(items[0][0]).shape[1]
     k = len(items)
     kp = lane_pad(k)
     steps_list = ([int(steps)] * k if isinstance(steps, (int, np.integer))
@@ -284,35 +298,17 @@ def batched_fit(items, steps=150, bucket: Optional[int] = None) -> list:
         raise ValueError(f"{len(steps_list)} step counts for {k} lanes")
     # one host-side buffer per array and ONE device put each — k small
     # transfers per lane would cost more than the fit at warm step counts
-    xs = np.zeros((kp, b, d), np.float64)
-    ys = np.zeros((kp, b), np.float64)
-    ms = np.zeros((kp, b), np.float64)
-    lls = np.full((kp, d), -0.7, np.float64)
-    las = np.zeros((kp,), np.float64)
-    lns = np.full((kp,), -2.0, np.float64)
+    (p0, xs, ys, ms), _ = _fit_inputs(items, int(b), kp)
     st = np.zeros((kp,), np.int32)
     st[:k] = steps_list
-    for i, (x, y, params0) in enumerate(items):
-        x = np.asarray(x, np.float64)
-        y_raw = np.asarray(y, np.float64)
-        n = x.shape[0]
-        if b < n:
-            raise ValueError(f"bucket {b} smaller than training set {n}")
-        mean = np.mean(y_raw)
-        std = max(float(np.std(y_raw)), 1e-6)
-        xs[i, :n] = x
-        ys[i, :n] = (y_raw - mean) / std
-        ms[i, :n] = 1.0
-        if params0 is not None:
-            lls[i] = np.asarray(params0.log_ls)
-            las[i] = np.asarray(params0.log_amp)
-            lns[i] = np.asarray(params0.log_noise)
-    # lanes k..kp-1 stay all-zero-mask (inert) with default params
     tracing.annotate(h2d_bytes=st.nbytes + np.dtype(dtype).itemsize * sum(
-        a.size for a in (xs, ys, ms, lls, las, lns)))
-    p0 = GPParams(jnp.asarray(lls, dtype), jnp.asarray(las, dtype),
-                  jnp.asarray(lns, dtype))
-    p = _fit_lanes(p0, jnp.asarray(xs, dtype), jnp.asarray(ys, dtype),
+        a.size for a in (xs, ys, ms, *p0)))
+    if k == 1:
+        p = _fit(*_lane0(dtype, p0, xs, ys, ms), steps=steps_list[0])
+        jax.block_until_ready(p.log_ls)
+        return [p]
+    p = _fit_lanes(jax.tree.map(lambda a: jnp.asarray(a, dtype), p0),
+                   jnp.asarray(xs, dtype), jnp.asarray(ys, dtype),
                    jnp.asarray(ms, dtype), jnp.asarray(st),
                    max_steps=max(steps_list))
     jax.block_until_ready(p.log_ls)
@@ -350,30 +346,17 @@ def fit_gp(x: np.ndarray, y: np.ndarray, steps: int = 150,
 
     ``bucket`` pads the training set to a static shape (default: smallest
     power-of-two bucket); ``params0`` warm-starts Adam from a previous fit.
-    """
+    The fit is ``_fit``, the one-lane dispatch a lone ``batched_fit``
+    item runs, on the same inputs."""
     dtype = _dtype()
-    x = np.asarray(x, np.float64)
-    y_raw = np.asarray(y, np.float64)
-    n, d = x.shape
-    b = bucket_size(n) if bucket is None else int(bucket)
-    if b < n:
-        raise ValueError(f"bucket {b} smaller than training set {n}")
-    # normalize on the host: device ops on the unpadded (n,) array would
-    # compile per history size
-    mean = float(np.mean(y_raw))
-    std = max(float(np.std(y_raw)), 1e-6)
-    y_mean = jnp.asarray(mean, dtype)
-    y_std = jnp.asarray(std, dtype)
-    xp, ynp, mask = _pad(x, (y_raw - mean) / std, b, dtype)
+    b = bucket_size(np.shape(x)[0]) if bucket is None else int(bucket)
+    (p0, xs, ys, ms), [(mean, std)] = _fit_inputs([(x, y, params0)], b, 1)
     tracing.annotate(h2d_bytes=np.dtype(dtype).itemsize * (
-        xp.size + ynp.size + mask.size + 2))
-    if params0 is None:
-        p0 = GPParams(jnp.zeros(d, dtype) - 0.7, jnp.zeros((), dtype),
-                      jnp.zeros((), dtype) - 2.0)
-    else:
-        p0 = jax.tree.map(lambda a: jnp.asarray(a, dtype), params0)
-    p = _fit(p0, xp, ynp, mask, steps=steps)
-    return _posterior(p, xp, ynp, mask, y_mean, y_std)
+        sum(a.size for a in (xs, ys, ms, *p0)) + 2))
+    p0, xp, ynp, mask = _lane0(dtype, p0, xs, ys, ms)
+    p = _fit(p0, xp, ynp, mask, steps=int(steps))
+    return _posterior(p, xp, ynp, mask, jnp.asarray(mean, dtype),
+                      jnp.asarray(std, dtype))
 
 
 def make_posterior(params: GPParams, x: np.ndarray, y: np.ndarray,
@@ -488,33 +471,29 @@ def prewarm_bucket(d: int, bucket: int, fit_steps=(), k_pads=(),
     """Compile every jitted kernel on the ask path for one bucket shape,
     using throwaway data: the hyperparameter fit (one ``_fit`` variant per
     entry in ``fit_steps``), the exact posterior, the rank-1 appends, and
-    the q-EI scan (one variant per ``k_pads`` entry, at the real candidate
-    pool size ``n_cand``).  XLA caches compilations per shape signature,
-    so calling this off the request path moves the first-touch compile
-    cost out of ``ask``.
+    the q-EI scan (one ``_select_scan`` variant per ``k_pads`` entry, at
+    the real candidate pool size ``n_cand``).  XLA caches compilations
+    per shape signature, so calling this off the request path moves the
+    first-touch compile cost out of ``ask``.
+
+    ``fit_lanes`` and ``select_lanes`` are the lane-pad ladders of the
+    executor's co-batched dispatches: ``_fit_lanes`` at every
+    ``fit_steps`` entry and ``_select_lanes`` at the fixed ``SELECT_PAD``
+    scan length, per lane pad, so no co-batched dispatch compiles under
+    load.  Lane pad 1 is the one-lane view: the ``_fit`` of the same
+    steps and the ``_select_scan`` at ``SELECT_PAD``.
 
     Each program signature is warmed once per process: ``("fit", d,
-    bucket, steps)``, ``("fit_lanes", d, bucket, steps, lane_pad)``,
-    ``("posterior", d, bucket)``, ``("append", d, bucket)``,
-    ``("select", d, bucket, k_pad, n_cand)`` and ``("select_lanes", d,
-    bucket, lane_pad, n_cand)``, each at the current dtype.  A later
-    request for a warmed signature runs nothing, so the many experiments
-    of one space that a service starts together warm the grid once
-    between them; ``prewarm_stats()`` counts the outcomes, and a
-    ``gp.prewarm`` span (attrs ``bucket``, ``warmed``, ``skipped``)
-    wraps each call.
-
-    ``fit_lanes`` is the lane-pad ladder of the batched executor path
-    (ISSUE 8): for each lane count the ``_fit_lanes`` variant is
-    compiled at every ``fit_steps`` entry, so a fleet's first batched
-    refit dispatch doesn't pay its (bucket, steps, lane-pad) compile
-    under load.
-
-    ``select_lanes`` is the analogous lane-pad ladder of the batched
-    *ask* path (ISSUE 10): for each lane count the ``_select_lanes``
-    variant is compiled at the fixed ``SELECT_PAD`` scan length and the
-    real pool size ``n_cand``, so a shard's first co-batched refill
-    dispatch never XLA-compiles mid-run."""
+    bucket, steps)``, ``("fit_lanes", d, bucket, steps, lane_pad)`` for
+    lane pads above 1, ``("posterior", d, bucket)``, ``("append", d,
+    bucket)``, ``("select", d, bucket, k_pad, n_cand)`` (lane pad 1 of
+    ``select_lanes`` is ``k_pad`` ``SELECT_PAD``) and ``("select_lanes",
+    d, bucket, lane_pad, n_cand)`` for lane pads above 1, each at the
+    current dtype.  A later request for a warmed signature runs nothing,
+    so the many experiments of one space that a service starts together
+    warm the grid once between them; ``prewarm_stats()`` counts the
+    outcomes, and a ``gp.prewarm`` span (attrs ``bucket``, ``warmed``,
+    ``skipped``) wraps each call."""
     x = np.zeros((2, d), np.float64)
     x[1] = 0.5
     y = np.array([0.0, 1.0], np.float64)
@@ -534,7 +513,8 @@ def prewarm_bucket(d: int, bucket: int, fit_steps=(), k_pads=(),
             with _warming(tally, "fit", d, bucket, s) as todo:
                 if todo:
                     post = fit_gp(x, y, steps=s, bucket=bucket)
-            for lanes in sorted({lane_pad(int(kp)) for kp in fit_lanes}):
+            for lanes in sorted({lane_pad(int(kp)) for kp in fit_lanes}
+                                - {1}):
                 with _warming(tally, "fit_lanes", d, bucket, s,
                               lanes) as todo:
                     if todo:
@@ -550,14 +530,17 @@ def prewarm_bucket(d: int, bucket: int, fit_steps=(), k_pads=(),
                              np.float32(0.5))
                 append_lie(posterior(), np.asarray(x[0], np.float32))
         cand = np.zeros((n_cand, d), np.float32)
-        for kp in sorted({int(k) for k in k_pads}):
+        sel_lanes = {lane_pad(int(s)) for s in select_lanes}
+        scans = {int(k) for k in k_pads} | (
+            {SELECT_PAD} if 1 in sel_lanes else set())
+        for kp in sorted(scans):
             if kp + 2 <= bucket:    # the scan needs kp free padded slots
                 with _warming(tally, "select", d, bucket, kp,
                               n_cand) as todo:
                     if todo:
                         select_batch(posterior(), cand, np.float32(1.0), kp)
         if SELECT_PAD + 2 <= bucket:
-            for lanes in sorted({lane_pad(int(s)) for s in select_lanes}):
+            for lanes in sorted(sel_lanes - {1}):
                 with _warming(tally, "select_lanes", d, bucket, lanes,
                               n_cand) as todo:
                     if todo:
@@ -626,77 +609,34 @@ def append_lie(post: GPPosterior, xn: jnp.ndarray) -> GPPosterior:
     return _append_norm(post, xn, _dot(kvec, post.alpha))
 
 
-@functools.partial(jax.jit, static_argnames=("k_pad",))
-def _select_scan(post: GPPosterior, cand: jnp.ndarray, best: jnp.ndarray,
-                 k: jnp.ndarray, k_pad: int):
-    """q-EI by sequential constant-liar greedy, fully inside one jitted
-    scan: argmax EI over the candidate pool, fold the pick in as a lie,
-    repeat.  The scan length is padded to ``k_pad`` (a power of two) with
-    the live count ``k`` traced, so varying batch sizes share one compile
-    per bucket; steps past ``k`` are computed then reverted wholesale."""
-    m = cand.shape[0]
+# ------------------------------------------------------------ q-EI select
+def _qei_lanes(post: GPPosterior, cand: jnp.ndarray, best: jnp.ndarray,
+               k: jnp.ndarray, k_pad: int):
+    """q-EI by sequential constant-liar greedy, for k experiments at
+    once: argmax EI over the candidate pool, fold the pick in as a lie,
+    repeat, fully inside one jitted scan.  Every posterior leaf, the
+    candidate pool (kl,m,d), the EI threshold ``best`` (kl,) and the live
+    pick count ``k`` (kl,) carry a leading lane axis, and one scan
+    advances all lanes together.  The scan length is padded to ``k_pad``
+    with each lane's ``k`` traced, so varying batch sizes share one
+    compile per (bucket, k_pad, lane-pad).
 
-    def step(carry, i):
-        p, taken = carry
-        ei = expected_improvement(p, cand, best)
-        ei = jnp.where(taken, -jnp.inf, ei)
-        j = jnp.argmax(ei)
-        p2 = append_lie(p, cand[j])
-        live = i < k
-        p = jax.tree.map(lambda new, old: jnp.where(live, new, old), p2, p)
-        taken = jnp.where(live, taken.at[j].set(True), taken)
-        return (p, taken), j
-
-    (post, _), picks = jax.lax.scan(
-        step, (post, jnp.zeros((m,), bool)), jnp.arange(k_pad))
-    return picks, post
-
-
-def select_batch(post: GPPosterior, cand: jnp.ndarray, best,
-                 k: int) -> Tuple[jnp.ndarray, GPPosterior]:
-    """Pick k batch points by greedy q-EI with constant-liar updates in
-    one jitted pass.  Returns (picked candidate indices (k,), posterior
-    with the k lies folded in).  The posterior must have >= k free slots;
-    compiles once per (bucket, next-power-of-two(k))."""
-    k = int(k)
-    k_pad = 1 << max(0, k - 1).bit_length()
-    picks, post = _select_scan(post, jnp.asarray(cand),
-                               jnp.asarray(best, post.y_mean.dtype),
-                               jnp.asarray(k, jnp.int32), k_pad)
-    return picks[:k], post
-
-
-# ----------------------------------------------------- batched q-EI select
-@functools.partial(jax.jit, static_argnames=("k_pad",))
-def _select_lanes(post: GPPosterior, cand: jnp.ndarray, best: jnp.ndarray,
-                  k: jnp.ndarray, k_pad: int):
-    """Lane-batched ``_select_scan``: every posterior leaf, the candidate
-    pool (kl,m,d), the EI threshold ``best`` (kl,) and the live pick
-    count ``k`` (kl,) carry a leading lane axis, and one greedy
-    constant-liar scan advances all lanes together.
-
-    Unlike the serial scan — which recomputes the full cross-covariance
-    ``kq = cov(cand, X)`` and whitened solve ``v = L⁻¹kqᵀ`` (O(b²m))
-    every step — the batched scan pays that factorization ONCE per
-    dispatch and extends it incrementally: a lie append adds one bordered
-    Cholesky row, so only one new column of ``kq`` (O(md)), one forward-
-    substitution row of ``v`` (O(bm)) and a rank-1 update of the
-    predictive-variance partials change per step.  The step-0 EI is
-    algebraically the same quantity ``ops.gp_ei`` computes (mirrored
-    here so the factors stay live in the scan carry); every serial step
-    after it drops from O(b²m) to O(bm), which is what makes the batched
-    plane cheaper per ask than the serial path even on a single-core CPU
-    host where vmap buys no parallelism (see benchmarks/bench_ask.py).
+    The scan pays the cross-covariance ``kq = cov(cand, X)`` and the
+    whitened solve ``v = L⁻¹kqᵀ`` (O(b²m)) ONCE per dispatch and extends
+    them incrementally: a lie append adds one bordered Cholesky row, so
+    only one new column of ``kq`` (O(md)), one forward-substitution row
+    of ``v`` (O(bm)) and a rank-1 update of the predictive-variance
+    partials change per pick.  The step-0 EI is algebraically the same
+    quantity ``ops.gp_ei`` computes (mirrored here so the factors stay
+    live in the scan carry).
 
     Lanes are independent: a lane whose own ``k`` is spent (and the
     all-zero-mask lane padding, where k == 0) keeps computing but has
     its posterior and taken-mask updates reverted — the carried
     ``kq/v/ss`` factors are deliberately left hot, since a dead lane's
     later picks and factors are discarded by the caller and never feed
-    another lane.  Mixed batch sizes share one compile per (bucket,
-    k_pad, lane-pad) triple."""
+    another lane."""
     kl, m = cand.shape[0], cand.shape[1]
-    lanes = jnp.arange(kl)
 
     def factorize(p, c):
         kq = matern52(c, p.x, p.params) * p.mask[None, :]        # (m,b)
@@ -758,6 +698,37 @@ def _select_lanes(post: GPPosterior, cand: jnp.ndarray, best: jnp.ndarray,
     return picks.T, post                                     # (kl,k_pad)
 
 
+@functools.partial(jax.jit, static_argnames=("k_pad",))
+def _select_lanes(post: GPPosterior, cand: jnp.ndarray, best: jnp.ndarray,
+                  k: jnp.ndarray, k_pad: int):
+    """``_qei_lanes`` over a lane axis of k experiments."""
+    return _qei_lanes(post, cand, best, k, k_pad)
+
+
+@functools.partial(jax.jit, static_argnames=("k_pad",))
+def _select_scan(post: GPPosterior, cand: jnp.ndarray, best: jnp.ndarray,
+                 k: jnp.ndarray, k_pad: int):
+    """``_qei_lanes`` at one lane: ``cand`` (m,d), ``best`` and ``k``
+    scalars.  The lane axis is added and stripped inside the program,
+    so a lone select is one dispatch of the lane algorithm."""
+    picks, out = _qei_lanes(jax.tree.map(lambda a: a[None], post),
+                            cand[None], best[None], k[None], k_pad)
+    return picks[0], jax.tree.map(lambda a: a[0], out)
+
+
+def select_batch(post: GPPosterior, cand: jnp.ndarray, best,
+                 k: int) -> Tuple[jnp.ndarray, GPPosterior]:
+    """Pick k batch points by greedy q-EI with constant-liar updates in
+    one jitted pass.  Returns (picked candidate indices (k,), posterior
+    with the k lies folded in).  The posterior must have >= k free slots;
+    compiles once per (bucket, lane_pad(k))."""
+    k = int(k)
+    picks, post = _select_scan(post, jnp.asarray(cand),
+                               jnp.asarray(best, post.y_mean.dtype),
+                               jnp.asarray(k, jnp.int32), lane_pad(k))
+    return picks[:k], post
+
+
 def _inert_posterior(b: int, d: int, dtype) -> GPPosterior:
     """Lane padding for ``batched_select``: an empty posterior whose
     masked covariance is the identity — chol = I, alpha = 0, so EI and
@@ -773,16 +744,18 @@ def _inert_posterior(b: int, d: int, dtype) -> GPPosterior:
 
 
 def batched_select(items, k_pad: int = SELECT_PAD) -> list:
-    """Run k experiments' q-EI batch selections in ONE vmap'd dispatch.
+    """Run k experiments' q-EI batch selections in ONE dispatch.
 
     ``items`` is a sequence of ``(post, cand, best, k)`` tuples — post a
     ``GPPosterior``, cand (m,d) candidate pool, best the raw-units EI
     incumbent, k <= ``k_pad`` the live pick count — all sharing one
-    posterior bucket and one pool shape.  Posteriors are stacked along a
-    leading lane axis, the lane count is padded to the next power of two
-    with inert lanes, and the scan length is the fixed ``k_pad`` (default
-    ``SELECT_PAD``) with per-lane k traced, so XLA compiles once per
-    (bucket, k_pad, lane-pad) triple regardless of each lane's batch
+    posterior bucket and one pool shape.  The scan length is the fixed
+    ``k_pad`` (default ``SELECT_PAD``) with per-lane k traced.  One item
+    runs ``_select_scan`` at ``k_pad``, the executable ``select_batch``
+    runs for a batch of that pad; k > 1 items are stacked along a
+    leading lane axis, the lane count padded to the next power of two
+    with inert lanes, and run by ``_select_lanes``, which compiles once
+    per (bucket, k_pad, lane-pad) triple regardless of each lane's batch
     size.  Returns a list of k ``(picks, post)`` pairs exactly as
     ``select_batch`` would produce — picks (k_i,) candidate indices,
     post the lane's posterior with its k_i lies folded in."""
@@ -810,9 +783,15 @@ def batched_select(items, k_pad: int = SELECT_PAD) -> list:
         cands[i] = cand
         bests[i] = float(best)
         ks[i] = int(k)
-    posts.extend(_inert_posterior(b, d, dtype) for _ in range(klp - kl))
     tracing.annotate(h2d_bytes=ks.nbytes + np.dtype(dtype).itemsize * (
         cands.size + bests.size))
+    if kl == 1:
+        picks, out = _select_scan(posts[0], jnp.asarray(cands[0]),
+                                  jnp.asarray(bests[0], dtype),
+                                  jnp.asarray(ks[0]), int(k_pad))
+        jax.block_until_ready(picks)
+        return [(picks[:int(ks[0])], out)]
+    posts.extend(_inert_posterior(b, d, dtype) for _ in range(klp - kl))
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *posts)
     picks, posts_out = _select_lanes(
         stacked, jnp.asarray(cands, dtype), jnp.asarray(bests, dtype),

@@ -2,7 +2,7 @@
 
 ``enable_compile_cache()`` is called once by each entry point that a user
 runs (``python -m repro.launch.cli``, ``python -m repro.launch.train``,
-``python -m benchmarks.run``, ``python chip_smoke.py``) before its first
+``python chip_smoke.py``) before its first
 compile.  It is never called when a module is imported, so library users
 and the tests keep JAX's own default.
 

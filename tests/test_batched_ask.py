@@ -4,13 +4,13 @@
 on a lane axis and runs them in ONE vmap'd dispatch; the pump publishes
 refill demand as ``AskSpec`` snapshots the FitExecutor gathers by
 (runner, bucket, k_pad, pool-shape) group.  This file pins the
-equivalence (batched picks == serial picks), the compile discipline
-(one XLA compile per (bucket, k_pad, lane-pad) triple), the
-variable-step fit-lane merge (frozen-lane params bit-identical, the
-steps-free group key co-batches mixed ladder rungs), the PRIO_MISS
-latency contract (miss asks never wait out the gather window) and —
-under REPRO_CONTENTION — a 16-experiment live-pump run through the
-shared executor."""
+equivalence (batched and one-lane picks == the serial reference's), the
+compile discipline (one XLA compile per (bucket, k_pad, lane-pad)
+triple), the variable-step fit-lane merge (frozen-lane params
+bit-identical, the steps-free group key co-batches mixed ladder rungs),
+the PRIO_MISS latency contract (miss asks never wait out the gather
+window) and — under REPRO_CONTENTION — a 16-experiment live-pump run
+through the shared executor."""
 import os
 import tempfile
 import threading
@@ -27,6 +27,8 @@ from repro.core.experiment import ExperimentConfig
 from repro.core.space import Param, Space, strip_internal
 from repro.core.suggest import Observation, gp, make_optimizer
 from repro.core.suggest.bayesopt import run_ask_lanes
+
+import gp_oracle
 
 
 def _space():
@@ -49,14 +51,18 @@ def _wait(predicate, timeout=10.0, every=0.01):
 
 def _posteriors(k, n=14, d=3, bucket=32, seed=0):
     """k fitted GP posteriors over distinct histories, one shape bucket,
-    with (candidate pool, incumbent) per lane."""
+    with (candidate pool, incumbent) per lane.  The hyperparameters come
+    from the serial reference fit, so the posteriors do not move with
+    the fit program under test."""
     rng = np.random.default_rng(seed)
     lanes = []
     for i in range(k):
         x = rng.random((n + i, d))
         w = rng.random(d)
         y = np.sin(3.0 * x @ w) + 0.1 * rng.standard_normal(n + i)
-        post = gp.fit_gp(x, y, steps=25, bucket=bucket)
+        post = gp.make_posterior(
+            gp_oracle.fit_params(x, y, 25, bucket=bucket), x, y,
+            bucket=bucket)
         cand = rng.random((64, d)).astype(np.float32)
         lanes.append((post, cand, float(np.max(y))))
     return lanes
@@ -64,26 +70,32 @@ def _posteriors(k, n=14, d=3, bucket=32, seed=0):
 
 # ---------------------------------------------------- gp.batched_select
 def test_batched_select_matches_serial_select():
-    """k lanes through one vmap'd q-EI dispatch must pick the *same
-    candidates* as k independent select_batch calls (exact index
-    equality — the suggestion parity contract, atol-free), and land on
-    the same lie-folded posterior up to float32 program-order rounding
-    (the lane-stacked solves are a different XLA program, so alpha/chol
-    drift at the 1e-4 level on O(10) magnitudes)."""
+    """k lanes through one q-EI dispatch (``_select_lanes``) and each
+    lane alone (the one-lane view ``_select_scan``, through
+    ``select_batch`` and a one-item ``batched_select``) must pick the
+    *same candidates* as the serial recompute-per-pick scan
+    (``tests/gp_oracle.py``) — exact index equality, the suggestion
+    parity contract — and land on the same lie-folded posterior up to
+    float32 program-order rounding (the incremental factor updates are a
+    different XLA program, so alpha/chol drift at the 1e-4 level on
+    O(10) magnitudes)."""
     import jax
     lanes = _posteriors(4)
     ks = [2, 5, 8, 3]
     out = gp.batched_select(
         [(post, cand, best, k)
          for (post, cand, best), k in zip(lanes, ks)])
-    for (post, cand, best), k, (picks, lane_post) in zip(lanes, ks, out):
-        solo_picks, solo_post = gp.select_batch(post, cand, best, k)
-        np.testing.assert_array_equal(np.asarray(picks),
-                                      np.asarray(solo_picks))
-        for got, want in zip(jax.tree.leaves(lane_post),
-                             jax.tree.leaves(solo_post)):
-            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       atol=5e-4)
+    for (post, cand, best), k, lane in zip(lanes, ks, out):
+        want_picks, want_post = gp_oracle.select(post, cand, best, k)
+        for picks, lane_post in (
+                lane, gp.select_batch(post, cand, best, k),
+                gp.batched_select([(post, cand, best, k)])[0]):
+            np.testing.assert_array_equal(np.asarray(picks),
+                                          np.asarray(want_picks))
+            for got, want in zip(jax.tree.leaves(lane_post),
+                                 jax.tree.leaves(want_post)):
+                np.testing.assert_allclose(np.asarray(got),
+                                           np.asarray(want), atol=5e-4)
 
 
 def test_batched_select_one_dispatch_one_compile():
@@ -100,20 +112,26 @@ def test_batched_select_one_dispatch_one_compile():
     gp.batched_select(list(reversed(items[:2])))    # different k order
     gp.batched_select(items[2:4])                   # different lanes/k
     assert gp._select_lanes._cache_size() == mid
+    scans = gp._select_scan._cache_size()
     gp.batched_select(items[:1])                    # lane_pad(1) == 1
-    assert gp._select_lanes._cache_size() == mid + 1
+    assert gp._select_lanes._cache_size() == mid    # the one-lane view
+    assert gp._select_scan._cache_size() <= scans + 1
 
 
 def test_prewarm_compiles_select_lanes():
     """Satellite: ``prewarm_bucket(select_lanes=(1, 2))`` compiles the
-    batched-select variant per lane pad — a later first dispatch at
+    batched-select variant per lane pad — lane pad 1 as the one-lane
+    ``_select_scan`` at ``SELECT_PAD`` — and a later first dispatch at
     those pads must not add a compile."""
     d, bucket, m = 5, 16, 32         # distinctive shapes: a fresh probe
     before = gp._select_lanes._cache_size()
+    scans = gp._select_scan._cache_size()
     gp.prewarm_bucket(d, bucket, fit_steps=(5,), k_pads=(1,),
                       n_cand=m, select_lanes=(1, 2))
     after = gp._select_lanes._cache_size()
-    assert after == before + 2       # lane pads 1 and 2
+    assert after == before + 1       # lane pad 2
+    scans_after = gp._select_scan._cache_size()
+    assert scans_after == scans + 2  # k_pad 1, and lane pad 1 at SELECT_PAD
     # idempotent: re-warming and real dispatches at those pads reuse it
     gp.prewarm_bucket(d, bucket, fit_steps=(5,), k_pads=(1,),
                       n_cand=m, select_lanes=(1, 2))
@@ -123,7 +141,9 @@ def test_prewarm_compiles_select_lanes():
     post = gp.fit_gp(x, y, steps=5, bucket=bucket)
     cand = rng.random((m, d)).astype(np.float32)
     gp.batched_select([(post, cand, 1.0, 4), (post, cand, 1.0, 2)])
+    gp.batched_select([(post, cand, 1.0, 3)])
     assert gp._select_lanes._cache_size() == after
+    assert gp._select_scan._cache_size() == scans_after
 
 
 # ---------------------------------------------- variable-step fit lanes
@@ -410,7 +430,7 @@ def test_sixteen_live_pumps_cobatch_refills():
             > 1.0, timeout=120.0), \
             f"refills never co-batched: {pipeline.fit_executor().snapshot()}"
         for exp in exps:
-            assert client.status(exp).observed >= 20
+            assert client.status(exp).observations >= 20
     finally:
         for exp in exps:
             client.stop(exp)

@@ -2,10 +2,11 @@
 
 The FitExecutor coalesces queued batchable fits sharing a
 (runner, bucket, steps) group into ONE vmap'd dispatch
-(``gp.batched_fit``); this file pins the equivalence (batched params ==
-serial params), the compile discipline (one XLA compile per lane-pad,
-zero on re-dispatch), the grouping rule (mixed buckets never co-batch),
-the PRIO_MISS latency contract (urgent fits skip the gather window) and
+(``gp.batched_fit``); this file pins the equivalence (batched and
+one-lane params == the serial reference's), the compile discipline (one
+XLA compile per lane-pad, zero on re-dispatch), the grouping rule (mixed
+buckets never co-batch), the PRIO_MISS latency contract (urgent fits
+skip the gather window) and
 — under REPRO_CONTENTION — k=16 real concurrent refits through the
 executor."""
 import os
@@ -20,6 +21,9 @@ from repro.api.pipeline import (BatchableFit, FitExecutor, FitLane,
                                 PRIO_IDLE, PRIO_MISS, RETRY)
 from repro.core.space import Param, Space
 from repro.core.suggest import Observation, gp, make_optimizer
+from repro.core.suggest.bayesopt import FitSpec, run_fit_lanes
+
+import gp_oracle
 
 
 def _space():
@@ -53,19 +57,39 @@ def _experiments(k, n=20, d=4, seed=0):
 
 # ------------------------------------------------------- gp.batched_fit
 def test_batched_fit_matches_serial_fits():
-    """k lanes through one vmap'd dispatch must land on the same
-    hyperparameters as k independent fit_gp calls (same steps, same
-    warm start) — lanes are independent by construction."""
+    """k lanes through one dispatch (``_fit_lanes``) and each lane alone
+    (the one-lane view ``_fit``) must land on the hyperparameters of the
+    serial reference — Adam over autodiff of the XLA neg-MLL, same steps,
+    same start (``tests/gp_oracle.py``): lanes are independent by
+    construction."""
     items = _experiments(5)
     batched = gp.batched_fit(items, steps=25, bucket=32)
     for (x, y, p0), bp in zip(items, batched):
-        post = gp.fit_gp(x, y, steps=25, params0=p0, bucket=32)
-        np.testing.assert_allclose(bp.log_ls, post.params.log_ls,
-                                   atol=1e-4)
-        np.testing.assert_allclose(bp.log_amp, post.params.log_amp,
-                                   atol=1e-4)
-        np.testing.assert_allclose(bp.log_noise, post.params.log_noise,
-                                   atol=1e-4)
+        want = gp_oracle.fit_params(x, y, steps=25, params0=p0, bucket=32)
+        alone = gp.batched_fit([(x, y, p0)], steps=25, bucket=32)[0]
+        for got in (bp, alone):
+            for g, w in zip(got, want):
+                np.testing.assert_allclose(g, w, atol=1e-4)
+
+
+def test_lone_fit_is_one_lane_dispatch_of_fit():
+    """A one-spec ``run_fit_lanes`` (the executor's lone refit) and
+    ``fit_gp`` run one executable, ``_fit``, on the same inputs: the
+    hyperparameters are identical, and between them they add one
+    ``_fit`` jit entry and no ``_fit_lanes`` one."""
+    rng = np.random.default_rng(11)
+    x = rng.random((11, 6))                 # d=6, bucket 16, 7 steps:
+    y = np.sin(3.0 * x.sum(1))              # shapes no other test fits
+    p0 = gp.GPParams(np.full(6, -0.5, np.float32), np.float32(0.1),
+                     np.float32(-1.5))
+    fits, lanes = gp._fit._cache_size(), gp._fit_lanes._cache_size()
+    (got,), _ = run_fit_lanes([FitSpec(16, 7, x, y, p0, None,
+                                       run_fit_lanes)])
+    want = gp.fit_gp(x, y, steps=7, params0=p0, bucket=16).params
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    assert gp._fit._cache_size() == fits + 1
+    assert gp._fit_lanes._cache_size() == lanes
 
 
 def test_batched_fit_one_dispatch_one_compile():

@@ -100,12 +100,12 @@ def test_concurrent_requests_warm_once_and_return_compiled(monkeypatch):
     ({"d": 7}, "_posterior"),
     ({"n_cand": 28}, "_select_scan"),
     ({"n_cand": 36}, "_select_lanes"),
-    ({"select_lanes": (2,)}, "_select_lanes"),
+    ({"select_lanes": (4,)}, "_select_lanes"),
     ({"x64": True}, "_posterior"),
 ])
 def test_any_other_signature_still_warms(variant, jitted):
     base = {"d": 6, "bucket": 16, "k_pads": (1,), "n_cand": 20,
-            "select_lanes": (1,)}
+            "select_lanes": (2,)}
     gp.prewarm_bucket(**base)
     kw = dict(base, **variant)
     x64 = kw.pop("x64", False)
@@ -115,6 +115,25 @@ def test_any_other_signature_still_warms(variant, jitted):
         gp.prewarm_bucket(**kw)
     assert gp.prewarm_stats()["warmed"] > stats0["warmed"]
     assert getattr(gp, jitted)._cache_size() == before + 1
+
+
+def test_lane_pad_one_is_the_one_lane_signature():
+    """Lane pad 1 of ``fit_lanes`` and of ``select_lanes`` is the
+    one-lane view: the ``fit`` signature at the same steps and the
+    ``select`` one at ``SELECT_PAD``.  Asking for both warms each
+    executable once, and no lane program."""
+    d, bucket, m = 11, 16, 20        # shapes no other test asks for
+    stats0 = gp.prewarm_stats()
+    before = {name: getattr(gp, name)._cache_size() for name in JITTED}
+    gp.prewarm_bucket(d, bucket, fit_steps=(3,), fit_lanes=(1,),
+                      k_pads=(gp.SELECT_PAD,), n_cand=m, select_lanes=(1,))
+    grown = {name: getattr(gp, name)._cache_size() - before[name]
+             for name in JITTED}
+    # fit, posterior, append and select: four signatures, none skipped
+    assert gp.prewarm_stats()["warmed"] - stats0["warmed"] == 4
+    assert gp.prewarm_stats()["skipped"] == stats0["skipped"]
+    assert grown["_fit"] == 1 and grown["_select_scan"] == 1
+    assert grown["_fit_lanes"] == 0 and grown["_select_lanes"] == 0
 
 
 def test_prewarm_span_names_bucket_and_outcomes():
